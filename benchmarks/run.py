@@ -8,7 +8,8 @@ modeled-vs-paper comparison where the paper reports numbers.
   fig4       — Fig. 4 system speedup/energy vs CPU across 6 workloads
   validation — Sec. II-A validation (TMR ~80%, ps switching, threshold)
   archmap    — beyond-paper: 10 LM archs mapped onto the IMC hierarchy
-  kernels    — Pallas kernel microbenches (interpret mode) vs jnp oracle
+  kernels    — Pallas kernel microbenches vs jnp oracle (interpret mode
+               on CPU, compiled on a TPU)
   mvm        — functional analog MVM (bitline/XNOR kernels) vs jnp einsum
   wer        — fused multi-temperature campaign (one launch, one compile)
                vs the old per-temperature-loop engine semantics and the
@@ -223,12 +224,13 @@ def bench_archmap():
 
 
 def bench_kernels():
-    """Pallas kernels (interpret mode) vs jnp oracle — correctness + timing."""
+    """Pallas kernels vs jnp oracle — correctness + timing (interpret mode
+    on CPU, compiled on a TPU)."""
     from repro.core import llg
     from repro.core.params import AFMTJ_PARAMS
     from repro.kernels import ops, ref
 
-    print("# kernels: pallas (interpret) vs ref")
+    print(f"# kernels: pallas ({jax.default_backend()}) vs ref")
     print("name,us_per_call,derived")
     th = jnp.linspace(0.05, 0.25, 512)
     m0 = jax.vmap(lambda t: llg.initial_state(AFMTJ_PARAMS, t, 0.3))(th)
@@ -1099,7 +1101,14 @@ def bench_scale():
     (per-device lane plans + WER bit-identity — the deterministic half of
     scaling on a wall-clock-less CI box), and the XLA tuning profile
     applied to a child environment.  Ends with the stale-droppings GC
-    sweep over the default cache dir."""
+    sweep over the default cache dir.  CPU only: on an accelerator the
+    parent holds the device its children would need, so it refuses."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "bench 'scale' measures forced host devices in child processes "
+            "and runs on the CPU backend only (JAX_PLATFORMS=cpu): on "
+            f"{jax.default_backend()!r} this process holds the device, so "
+            "its children could not reach it")
     import hashlib
     import json as _json
     import subprocess
@@ -1274,7 +1283,10 @@ def main() -> None:
                      f"choices: {sorted(BENCHES)}")
     else:
         names = list(BENCHES)
+    from repro.runtime.compile_cache import enable_compile_cache
     from repro.runtime.fault import StepWatchdog
+
+    enable_compile_cache()
 
     # per-bench wall-time watchdog: a bench that blows past 3x the running
     # average usually means an accidental full-mode shape or a compile

@@ -21,6 +21,7 @@ import tempfile
 from pathlib import Path
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.core.params import DeviceParams
@@ -229,13 +230,18 @@ def gc_stale_claims(cache_dir: Optional[str] = None,
 
 # --------------------------------------------------------------- campaigns
 def campaign_key(p: DeviceParams, grid, backend: str) -> str:
-    """Content hash of everything the crossing-time tensor depends on."""
+    """Content hash of everything the crossing-time tensor depends on.
+
+    The platform (``"cpu"``, ``"tpu"``) is part of the key, so a surface
+    integrated on one platform is never served as another's; the
+    resume-slice and streaming keys derive from this one and inherit it."""
     return content_key({
         "v": KERNEL_VERSION,
         "layout": CELLS_LAYOUT,
         "params": dataclasses.asdict(p),
         "grid": dataclasses.asdict(grid),
         "backend": backend,
+        "platform": jax.devices()[0].platform,
     })
 
 

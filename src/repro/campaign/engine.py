@@ -59,7 +59,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.campaign import cache as _cache
@@ -142,15 +141,16 @@ def _integrate_impl(state, seeds, sigma, budget, lane_params=None, *,
     if n_dev == 1:
         return tile_fn(state, seeds, sigma, budget, lane_params)
     mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("cells",))
-    # check_rep=False: shard_map has no replication rule for pallas_call;
-    # every output is fully sharded along cells anyway
+    # check_vma=False: pallas_call has no varying-axes rule; every output
+    # is fully sharded along cells anyway
     specs = (P(None, "cells"), P("cells"), P("cells"), P("cells"))
     if lane_params is None:
-        fn = shard_map(tile_fn, mesh=mesh, in_specs=specs,
-                       out_specs=P(None, "cells"), check_rep=False)
+        fn = jax.shard_map(tile_fn, mesh=mesh, in_specs=specs,
+                           out_specs=P(None, "cells"), check_vma=False)
         return fn(state, seeds, sigma, budget)
-    fn = shard_map(tile_fn, mesh=mesh, in_specs=specs + (P(None, "cells"),),
-                   out_specs=P(None, "cells"), check_rep=False)
+    fn = jax.shard_map(tile_fn, mesh=mesh,
+                       in_specs=specs + (P(None, "cells"),),
+                       out_specs=P(None, "cells"), check_vma=False)
     return fn(state, seeds, sigma, budget, lane_params)
 
 
@@ -172,6 +172,9 @@ _integrate_sharded = jax.jit(_integrate_impl,
 _integrate_donated = jax.jit(_integrate_impl,
                              static_argnames=_INTEGRATE_STATICS,
                              donate_argnums=(0,))
+# by ``donate``: the jit objects ``run_campaign`` compiles each launch
+# through ahead of its dispatch (see ``compile_launch``)
+_INTEGRATE_JITS = {False: _integrate_sharded, True: _integrate_donated}
 
 
 def _device_plan(span_cells: int, devices: Optional[int]) -> Tuple[int, int]:
@@ -636,9 +639,11 @@ def run_campaign(
     it never finished — and because the stored row is the kernel's f32
     output verbatim, the resumed assembly is bit-identical to an
     uninterrupted run.  Slice checkpoints are retired once the
-    whole-campaign entry is durable.  A launch that fails to dispatch or
-    sync is retried up to ``max_retries`` times with exponential backoff
-    (``retry_backoff_s`` base).  ``on_slice_complete(i, n_launches)`` fires
+    whole-campaign entry is durable.  Each launch's program is compiled
+    before it is dispatched, and a compiler refusal raises at once; a
+    launch that then fails to dispatch or sync is retried up to
+    ``max_retries`` times with exponential backoff (``retry_backoff_s``
+    base).  ``on_slice_complete(i, n_launches)`` fires
     after each freshly-integrated launch is checkpointed — the hook the
     kill/resume tests use to die at a deterministic point.
 
@@ -787,17 +792,35 @@ def run_campaign(
             c1 = state.shape[1]              # include the total-bucket pad
         return c0, c1
 
-    def dispatch(a: int, b: int):
+    def launch_plan(a: int, b: int):
         c0, c1 = span_cols(a, b)
         n_dev, plan_cols = _device_plan(c1 - c0, devices)
+        statics = dict(p=p, dt=grid.dt, n_steps=n_static,
+                       switch_threshold=float(grid.switch_threshold),
+                       backend=backend, n_dev=n_dev, chunk=int(chunk))
+        return c0, c1, plan_cols, statics
+
+    def compile_launch(a: int, b: int) -> None:
+        """Compile one launch's program before it is dispatched.  A
+        compiler refusal is deterministic, so it raises here, outside every
+        retry ladder; the dispatch then reuses the compiled executable
+        (repeat calls hit the jit caches)."""
+        _, _, cols, statics = launch_plan(a, b)
+        shapes = [jax.ShapeDtypeStruct(x.shape[:-1] + (cols,), x.dtype)
+                  for x in (state, seeds, sigma, budget)]
+        lp = (None if lane_params is None else
+              jax.ShapeDtypeStruct((lane_params.shape[0], cols),
+                                   lane_params.dtype))
+        _INTEGRATE_JITS[donate].lower(*shapes, lp, **statics).compile()
+
+    def dispatch(a: int, b: int):
+        c0, c1, plan_cols, statics = launch_plan(a, b)
         st, sd, sg, bd, lp = _pad_lanes(
             state[:, c0:c1], seeds[c0:c1], sigma[c0:c1], budget[c0:c1],
             None if lane_params is None else lane_params[:, c0:c1],
             plan_cols - (c1 - c0), p)
         fn = _integrate_donated if donate else _integrate_sharded
-        out = fn(st, sd, sg, bd, lp, p=p, dt=grid.dt, n_steps=n_static,
-                 switch_threshold=float(grid.switch_threshold),
-                 backend=backend, n_dev=n_dev, chunk=int(chunk))
+        out = fn(st, sd, sg, bd, lp, **statics)
         if not streaming:
             return out
         return _reduce_rows(out, kmin_dev, n_slices=b - a,
@@ -847,6 +870,7 @@ def run_campaign(
         time a retry needs them — detected via ``is_deleted`` and repaired
         by re-packing (bit-identical by construction)."""
         nonlocal state, seeds, sigma, budget, lane_params, n_computed
+        compile_launch(a, b)
         attempt = 0
         while True:
             try:
@@ -890,6 +914,7 @@ def run_campaign(
                     payloads[i] = hit
                     n_resumed += 1
                     continue
+            compile_launch(a, b)
             try:
                 outs[i] = dispatch(a, b)
             except Exception:                # retried in the sync loop

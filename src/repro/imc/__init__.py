@@ -44,6 +44,7 @@ _WRITE_PATH_EXPORTS = ("WritePolicy", "ArrayWriteResult", "MeasuredWrite",
                        "measured_write_timings", "write_surface",
                        "nominal_pulse")
 _MODEL_ANALOG_EXPORTS = ("ModelAccuracyReport", "fake_analog_matmul",
+                         "fake_kernel_operands",
                          "program_weights_cached", "programming_key",
                          "param_tree_hash", "model_forward_logits",
                          "analog_model_logits", "model_accuracy",

@@ -55,7 +55,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.circuit.bitline import BitlineParams, cell_conductance, column_ir_drop
@@ -269,9 +268,10 @@ def _mvm_sharded(v, g, *, adc_bits: int, i_max: float, interpret: bool,
     if n_dev == 1:
         return tile(v, g)
     mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("batch",))
-    # check_rep=False: shard_map has no replication rule for pallas_call
-    fn = shard_map(tile, mesh=mesh, in_specs=(P("batch", None), P(None, None)),
-                   out_specs=P("batch", None), check_rep=False)
+    # check_vma=False: pallas_call has no varying-axes rule
+    fn = jax.shard_map(tile, mesh=mesh,
+                       in_specs=(P("batch", None), P(None, None)),
+                       out_specs=P("batch", None), check_vma=False)
     return fn(v, g)
 
 

@@ -85,12 +85,12 @@ def _round_2sig(v: jnp.ndarray) -> jnp.ndarray:
     return jnp.round(v / p) * p
 
 
-def _fake_mvm_body(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
-                   adc_bits: int, apply_fet: bool, use_fail: bool,
-                   ir_drop: bool, has_imax: bool, decode: bool,
-                   interpret: bool, use_faults: bool = False,
+def _fake_operands(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
+                   apply_fet: bool, use_fail: bool, ir_drop: bool,
+                   has_imax: bool, decode: bool, use_faults: bool = False,
                    repair: Optional[RepairPolicy] = None):
-    """Traced fake-analog ``x @ w``: operand preamble + fused kernel.
+    """Traced operand preamble of the fake-analog ``x @ w``: the
+    ``(v, wn, fail, aux)`` the fused kernel consumes.
 
     Everything numeric mirrors ``program_weights`` / ``kernel_operands`` /
     ``analog_matmul`` step for step, with host floats replaced by traced
@@ -175,7 +175,19 @@ def _fake_mvm_body(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
     rows[ROW_G_AP], rows[ROW_G_FS] = full(g_ap), full(g_fs)
     rows[ROW_G_SCALE], rows[ROW_R_ACCESS] = (full(scal["g_scale"]),
                                              full(scal["r_access"]))
-    aux = jnp.stack(rows)
+    return v, wn, fail, jnp.stack(rows)
+
+
+def _fake_mvm_body(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
+                   adc_bits: int, apply_fet: bool, use_fail: bool,
+                   ir_drop: bool, has_imax: bool, decode: bool,
+                   interpret: bool, use_faults: bool = False,
+                   repair: Optional[RepairPolicy] = None):
+    """Traced fake-analog ``x @ w``: operand preamble + fused kernel."""
+    v, wn, fail, aux = _fake_operands(
+        x, w, bl, scal, apply_fet=apply_fet, use_fail=use_fail,
+        ir_drop=ir_drop, has_imax=has_imax, decode=decode,
+        use_faults=use_faults, repair=repair)
     return fake_analog_mac_pallas(v, wn, fail, aux, adc_bits=adc_bits,
                                   apply_fet=apply_fet,
                                   use_fail=use_fail or use_faults,
@@ -277,6 +289,30 @@ def fake_analog_matmul(
     return fn(x, w, bl, scal)
 
 
+def fake_kernel_operands(
+    w: jnp.ndarray,
+    x: jnp.ndarray,
+    kind: str = "afmtj",
+    cfg: AnalogConfig = AnalogConfig(),
+) -> Tuple[Tuple[jnp.ndarray, ...], Dict[str, Any]]:
+    """The ``(v, wn, fail, aux)`` operands and the static kernel flags that
+    ``fake_analog_matmul(w, x, kind, cfg)`` feeds the fused kernel — exposed
+    so parity checks run ``ref.ref_fake_analog(*operands, **flags)`` on
+    exactly the kernel's inputs instead of copying the preamble."""
+    bl = BitlineParams(rows=w.shape[0])
+    apply_fet, g_scale = _systematic_g_scale(cfg)
+    use_faults = _fake_faults_mode(cfg)
+    use_fail = cfg.write_ber > 0.0
+    scal = _fake_scalars(kind, cfg, bl, g_scale, None)
+    pre = jax.jit(functools.partial(
+        _fake_operands, bl=bl, apply_fet=apply_fet, use_fail=use_fail,
+        ir_drop=cfg.ir_drop, has_imax=False, decode=True,
+        use_faults=use_faults, repair=cfg.repair))
+    flags = dict(adc_bits=cfg.adc_bits, apply_fet=apply_fet,
+                 use_fail=use_fail or use_faults)
+    return pre(x, w, scal=scal), flags
+
+
 # ---------------------------------------------------------------------------
 # weight-programming cache (device path)
 # ---------------------------------------------------------------------------
@@ -300,10 +336,13 @@ def programming_key(w, kind: str, cfg: AnalogConfig,
                     bl: BitlineParams) -> str:
     """Content key over the *programming-relevant* axes only: sweeping
     ``adc_bits`` / ``full_scale_sigmas`` / ``v_read`` (pure read-out knobs)
-    hits the cache; TMR / corner / BER / seed / IR-drop re-program."""
+    hits the cache; TMR / corner / BER / seed / IR-drop re-program.  The
+    platform is keyed too, so a plane programmed on one is never served on
+    another."""
     spec = _resolved_variation(cfg)
     return _cache.content_key({
         "v": PROGRAMMING_VERSION,
+        "platform": jax.devices()[0].platform,
         "kind": kind,
         "w": _array_digest(w),
         "tmr": cfg.tmr,

@@ -28,6 +28,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BM = BN = BK = 128
+# Bit-line currents are f32 sums.  An f32 dot on a TPU defaults to one bf16
+# pass (inside Mosaic as in XLA), which rounds every conductance to 8
+# mantissa bits; HIGHEST keeps f32.  The CPU computes f32 either way.
+F32_DOT = jax.lax.Precision.HIGHEST
 
 
 def adc_quantize(i_bl: jnp.ndarray, adc_bits: int, i_max: float) -> jnp.ndarray:
@@ -56,7 +60,8 @@ def _mac_kernel(v_ref, g_ref, o_ref, acc_ref, *, nk: int, adc_bits: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        v_ref[...], g_ref[...], preferred_element_type=jnp.float32
+        v_ref[...], g_ref[...], precision=F32_DOT,
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(pl.program_id(2) == nk - 1)

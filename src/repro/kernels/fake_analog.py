@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bitline_mac import BM, BN, BK, _pad2, adc_quantize
+from repro.kernels.bitline_mac import (BM, BN, BK, F32_DOT, _pad2,
+                                       adc_quantize)
 
 # aux plane row layout (8, N) — per-column planes first, broadcast scalars
 # (stored across the full row) after
@@ -110,17 +111,18 @@ def pos_neg_conductance(wn, fail, g_ap, g_fs, g_scale, r_access, *,
 
 
 def _tile_g_diff(wn, fail, aux, *, apply_fet: bool, use_fail: bool):
-    """(BK, BN) differential conductance tile from the aux-plane scalars."""
+    """(BK, BN) differential conductance tile from the aux plane.
+
+    The scalars are read as whole ``(1, BN)`` rows (they are stored across
+    the row): Mosaic cannot broadcast a ``(1, 1)`` slice in both sublanes
+    and lanes.  Padded columns read zeros and carry att = 0."""
+    def row(r):
+        return aux[r:r + 1, :]
+
     tp, tn = pos_neg_conductance(
-        wn, fail,
-        aux[ROW_G_AP:ROW_G_AP + 1, :1],
-        aux[ROW_G_FS:ROW_G_FS + 1, :1],
-        aux[ROW_G_SCALE:ROW_G_SCALE + 1, :1],
-        aux[ROW_R_ACCESS:ROW_R_ACCESS + 1, :1],
-        apply_fet=apply_fet, use_fail=use_fail)
-    att_p = aux[ROW_ATT_POS:ROW_ATT_POS + 1, :]
-    att_n = aux[ROW_ATT_NEG:ROW_ATT_NEG + 1, :]
-    return att_p * tp - att_n * tn
+        wn, fail, row(ROW_G_AP), row(ROW_G_FS), row(ROW_G_SCALE),
+        row(ROW_R_ACCESS), apply_fet=apply_fet, use_fail=use_fail)
+    return row(ROW_ATT_POS) * tp - row(ROW_ATT_NEG) * tn
 
 
 def _fake_kernel(v_ref, w_ref, fail_ref, aux_ref, o_ref, acc_ref, *, nk: int,
@@ -132,7 +134,8 @@ def _fake_kernel(v_ref, w_ref, fail_ref, aux_ref, o_ref, acc_ref, *, nk: int,
     g_diff = _tile_g_diff(w_ref[...], fail_ref[...], aux_ref[...],
                           apply_fet=apply_fet, use_fail=use_fail)
     acc_ref[...] += jnp.dot(
-        v_ref[...], g_diff, preferred_element_type=jnp.float32
+        v_ref[...], g_diff, precision=F32_DOT,
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(pl.program_id(2) == nk - 1)

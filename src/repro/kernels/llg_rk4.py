@@ -214,15 +214,19 @@ def _llg_thermal_kernel(state_ref, seeds_ref, aux_ref, out_ref, *,
     ``variation`` the aux plane carries three more per-lane device rows
     (2 = alpha, 3 = B_k, 4 = g_scale) and the RK4 body reads those instead
     of the compile-time scalars — process corners become launch data."""
-    s = state_ref[...]
-    m1 = (s[0], s[1], s[2])
-    m2 = (s[3], s[4], s[5])
-    v = s[6]
-    seeds = seeds_ref[0]
-    sigma = aux_ref[0]
-    budget = aux_ref[1]
-    lane_params = ((aux_ref[2], aux_ref[3], aux_ref[4]) if variation
-                   else None)
+    # every lane quantity stays a (1, CELL_TILE) row: Mosaic cannot lay
+    # out the 1-D vectors that ``ref[k]`` would give the uint32 hash
+    def row(ref, k):
+        return ref[k:k + 1, :]
+
+    m1 = (row(state_ref, 0), row(state_ref, 1), row(state_ref, 2))
+    m2 = (row(state_ref, 3), row(state_ref, 4), row(state_ref, 5))
+    v = row(state_ref, 6)
+    seeds = seeds_ref[...]
+    sigma = row(aux_ref, 0)
+    budget = row(aux_ref, 1)
+    lane_params = ((row(aux_ref, 2), row(aux_ref, 3), row(aux_ref, 4))
+                   if variation else None)
     crossed = jnp.full_like(v, float(n_steps))
 
     body = _make_body(p, dt, n_steps, switch_threshold, sigma, seeds, v,
@@ -251,8 +255,7 @@ def _llg_thermal_kernel(state_ref, seeds_ref, aux_ref, out_ref, *,
 
         _, m1, m2, crossed = jax.lax.while_loop(
             cond, chunk_body, (0, m1, m2, crossed))
-    out = jnp.stack([m1[0], m1[1], m1[2], m2[0], m2[1], m2[2], v, crossed])
-    out_ref[...] = out
+    out_ref[...] = jnp.concatenate([*m1, *m2, v, crossed], axis=0)
 
 
 def llg_rk4_pallas(

@@ -72,8 +72,12 @@ def slice_seeds(base_seed: int, slice_index: int, cells: int) -> jnp.ndarray:
 
 
 def _uniform24(h: jnp.ndarray) -> jnp.ndarray:
-    """uint32 hash -> f32 uniform in (0, 1] using the top 24 bits."""
-    return ((h >> np.uint32(8)).astype(jnp.float32) + 1.0) * _INV_2_24
+    """uint32 hash -> f32 uniform in (0, 1] using the top 24 bits.
+
+    The 24-bit value goes through int32 on its way to f32: exact (it is
+    below 2**24), and Mosaic lowers int32 -> f32 but not uint32 -> f32."""
+    top = (h >> np.uint32(8)).astype(jnp.int32)
+    return (top.astype(jnp.float32) + 1.0) * _INV_2_24
 
 
 def normal_pair(seed: jnp.ndarray, counter: jnp.ndarray):

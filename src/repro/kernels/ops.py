@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU —
-the BlockSpecs/grids are written for TPU VMEM tiling and validated on CPU
-via the interpreter against ref.py.
+On a TPU the kernels compile through Mosaic.  On any other backend
+``interpret`` defaults to True, so the CPU test suite runs the same kernels
+under the Pallas interpreter and checks them against ref.py; what the
+chip's compiler accepts is checked by ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 

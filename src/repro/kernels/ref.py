@@ -142,9 +142,10 @@ def ref_llg_rk4(
 
 
 def ref_bitline_mac(v, g, adc_bits: int = 0, i_max: float = 1.0):
-    from repro.kernels.bitline_mac import adc_quantize
+    from repro.kernels.bitline_mac import F32_DOT, adc_quantize
 
-    i_bl = v.astype(jnp.float32) @ g.astype(jnp.float32)
+    i_bl = jnp.dot(v.astype(jnp.float32), g.astype(jnp.float32),
+                   precision=F32_DOT)
     return adc_quantize(i_bl, adc_bits, i_max)
 
 
@@ -153,14 +154,14 @@ def ref_fake_analog(v, wn, fail, aux, adc_bits: int = 0,
     """jnp oracle for ``fake_analog.fake_analog_mac_pallas``: same fused
     conductance replay (shared ``_tile_g_diff`` — the tile math cannot
     drift), full-array dot, shared ADC, decode gain."""
-    from repro.kernels.bitline_mac import adc_quantize
+    from repro.kernels.bitline_mac import F32_DOT, adc_quantize
     from repro.kernels.fake_analog import ROW_DECODE, ROW_I_MAX, _tile_g_diff
 
     g_diff = _tile_g_diff(jnp.asarray(wn, jnp.float32),
                           jnp.asarray(fail, jnp.float32),
                           jnp.asarray(aux, jnp.float32),
                           apply_fet=apply_fet, use_fail=use_fail)
-    i_bl = v.astype(jnp.float32) @ g_diff
+    i_bl = jnp.dot(v.astype(jnp.float32), g_diff, precision=F32_DOT)
     i_max = aux[ROW_I_MAX:ROW_I_MAX + 1, :]
     return adc_quantize(i_bl, adc_bits, i_max) * aux[ROW_DECODE:ROW_DECODE + 1, :]
 

@@ -23,6 +23,15 @@ import dataclasses
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code shards
+    through ``with_sharding_constraint`` and leaves propagation to XLA,
+    which the default explicit axes would refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,14 +39,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     leading pod axis (2 pods = 512 chips) for cross-pod data parallelism."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1):
     """Debug mesh over whatever devices exist (tests use 1-8 host devices)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import ARCHS
+from repro.core.params import CORNER_SS, VariationSpec
 from repro.imc.analog_pipeline import (AnalogConfig, analog_matmul,
                                        binary_matmul, mvm_accuracy,
                                        program_weights)
@@ -312,6 +313,33 @@ def test_fake_kernel_matches_oracle():
     out_r = np.asarray(ref.ref_fake_analog(v, wn, fail, aux, **kw))
     assert out_k.shape == (m, n)
     np.testing.assert_allclose(out_k, out_r, rtol=1e-6, atol=1e-6 * 1234.5)
+
+
+@pytest.mark.parametrize("cfg", [
+    AnalogConfig(adc_bits=8),
+    AnalogConfig(adc_bits=5, write_ber=0.02,
+                 variation=VariationSpec(corners=(CORNER_SS,))),
+], ids=["adc8", "adc5-ber-ss"])
+def test_fake_matmul_matches_oracle_on_its_operands(cfg):
+    """``fake_kernel_operands`` hands the oracle exactly what
+    ``fake_analog_matmul`` feeds the kernel (the chip smoke run's
+    projection check): the two agree to within one ADC level, on a few
+    outputs at most."""
+    from repro.imc.model_analog import fake_analog_matmul, fake_kernel_operands
+    from repro.kernels.fake_analog import ROW_DECODE, ROW_I_MAX
+
+    kx, kw = jax.random.split(jax.random.PRNGKey(5))
+    x = jax.random.normal(kx, (6, 150))
+    w = jax.random.normal(kw, (150, 70)) * 0.05
+    out = np.asarray(fake_analog_matmul(w, x, cfg=cfg))
+    operands, flags = fake_kernel_operands(w, x, cfg=cfg)
+    assert flags["adc_bits"] == cfg.adc_bits
+    assert flags["use_fail"] == (cfg.write_ber > 0.0)
+    want = np.asarray(ref.ref_fake_analog(*operands, **flags))
+    aux = np.asarray(operands[3])
+    lsb = aux[ROW_I_MAX] * aux[ROW_DECODE] / (2 ** (cfg.adc_bits - 1) - 1)
+    levels = np.rint(np.abs(out - want) / lsb)
+    assert levels.max() <= 1 and (levels > 0).mean() <= 0.01
 
 
 # --- mapping wiring ----------------------------------------------------------

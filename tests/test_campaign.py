@@ -396,6 +396,46 @@ def test_campaign_launch_retry_bounded(monkeypatch):
     assert calls["n"] == 4          # 1 dispatch + 1 sync + 2 bounded retries
 
 
+def test_campaign_compile_error_raises_without_retry(monkeypatch):
+    """A launch the compiler refuses fails identically on every attempt:
+    it raises at once, before any dispatch or back-off."""
+    from repro.campaign import engine
+
+    calls = {"n": 0}
+
+    def counted(*a, **kw):
+        calls["n"] += 1
+        raise AssertionError("a refused launch must never dispatch")
+
+    class Refused:
+        def lower(self, *a, **kw):
+            raise NotImplementedError("Unsupported cast: uint32 -> float32")
+
+    monkeypatch.setattr(engine, "_integrate_sharded", counted)
+    monkeypatch.setitem(engine._INTEGRATE_JITS, False, Refused())
+    with pytest.raises(NotImplementedError, match="Unsupported cast"):
+        engine.run_campaign(AFMTJ_PARAMS, _resume_grid(), backend="ref",
+                            use_cache=False, max_retries=3,
+                            retry_backoff_s=60.0)
+    assert calls["n"] == 0
+
+
+def test_campaign_key_differs_by_platform(monkeypatch):
+    """A surface integrated on one platform is never served as another's:
+    the first device's platform is part of the key."""
+    from repro.campaign.cache import campaign_key
+
+    grid = _resume_grid()
+    here = campaign_key(AFMTJ_PARAMS, grid, "pallas")
+    assert campaign_key(AFMTJ_PARAMS, grid, "pallas") == here
+
+    class Other:
+        platform = "cpu" if jax.devices()[0].platform == "tpu" else "tpu"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Other()])
+    assert campaign_key(AFMTJ_PARAMS, grid, "pallas") != here
+
+
 # ------------------------------------------------------------- grid/packing
 def test_pack_plane_layout():
     grid = CampaignGrid(voltages=(0.5, 1.0), pulse_widths=(100e-12,),

@@ -1,9 +1,10 @@
 """Runtime fault-tolerance tests: step watchdog EWMA clamping, SIGTERM
 preemption handling (install/uninstall/context-manager), and elastic
-re-meshing plans.  First coverage for ``runtime.fault`` / ``runtime.elastic``
-— pure-Python modules, no JAX."""
+re-meshing plans (``runtime.fault`` / ``runtime.elastic``, pure Python), and
+where ``runtime.compile_cache`` points JAX's compile cache."""
 import os
 import signal
+from pathlib import Path
 
 from repro.runtime.elastic import plan_elastic_remesh
 from repro.runtime.fault import FaultTolerantLoop, StepWatchdog
@@ -131,3 +132,28 @@ def test_elastic_halves_data_axis_preserving_global_batch():
 def test_elastic_returns_none_when_model_axis_cannot_fit():
     assert plan_elastic_remesh(15, model_axis=16, old_data_axis=16) is None
     assert plan_elastic_remesh(0, model_axis=8, old_data_axis=4) is None
+
+
+# --- compile cache -----------------------------------------------------------
+
+def test_compile_cache_honours_env_then_checkout_dir(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache goes
+    to the fixed, git-ignored ``.jax_cache/`` at the checkout root."""
+    import jax
+
+    from repro.runtime import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        root = Path(__file__).resolve().parents[1]
+        assert got == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -113,9 +113,7 @@ aopt = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float3
 jit = jax.jit(fn, in_shardings=(ps, ps, ps, None, bs),
               out_shardings=(ps, ps, ps, None, None), donate_argnums=(0,1,2))
 c = jit.lower(ap, aopt, aopt, jax.ShapeDtypeStruct((), jnp.int32), batch).compile()
-ca = c.cost_analysis()
-ca = ca[0] if isinstance(ca, list) else ca   # list-wrapped pre-jax-0.5
-print("COMPILED", ca["flops"] > 0)
+print("COMPILED", c.cost_analysis()["flops"] > 0)
 """
     r = subprocess.run([sys.executable, "-c", code],
                        env={**os.environ, "PYTHONPATH": str(REPO / "src")},
