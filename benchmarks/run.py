@@ -1284,23 +1284,13 @@ def main() -> None:
     else:
         names = list(BENCHES)
     from repro.runtime.compile_cache import enable_compile_cache
-    from repro.runtime.fault import StepWatchdog
 
     enable_compile_cache()
 
-    # per-bench wall-time watchdog: a bench that blows past 3x the running
-    # average usually means an accidental full-mode shape or a compile
-    # regression — flag it in the log (and BENCH.json meta) instead of
-    # letting it hide inside the total
-    wd = StepWatchdog(threshold=3.0, alpha=0.5)
     t0 = time.time()
-    for i, n in enumerate(names):
+    for n in names:
         print(f"\n=== {n} " + "=" * (60 - len(n)))
-        tb = time.time()
         BENCHES[n]()
-        if wd.observe(i, time.time() - tb):
-            print(f"# watchdog: bench '{n}' took "
-                  f"{time.time() - tb:.1f}s, >3x the running average")
     total = time.time() - t0
     print(f"\ntotal {total:.1f}s")
     if args.json:
@@ -1312,7 +1302,6 @@ def main() -> None:
                 "device_count": jax.device_count(),
                 "jax": jax.__version__,
                 "total_s": round(total, 3),
-                "straggler_benches": [names[i] for i in wd.straggler_steps],
                 "unix_time": int(time.time()),
             },
             "benchmarks": RECORDS,

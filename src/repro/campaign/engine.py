@@ -62,13 +62,15 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.campaign import cache as _cache
-from repro.campaign.grid import (CampaignGrid, log_horizon_bucket, next_pow2,
-                                 pack_campaign, pack_soa, pack_variation)
+from repro.campaign.grid import (CampaignGrid, bucket_cells,
+                                 log_horizon_bucket, next_pow2, pack_campaign,
+                                 pack_soa, pack_variation)
 from repro.core.montecarlo import thermal_sigma
 from repro.core.params import DeviceParams
 from repro.kernels import noise, ref
 from repro.kernels.llg_rk4 import CELL_TILE, llg_rk4_pallas
 from repro.kernels.ops import _default_interpret
+from repro.runtime import telemetry
 
 # Early-exit granularity [steps]: the kernel checks "is every lane done?"
 # once per chunk.  Small enough that a finished tile wastes < chunk steps,
@@ -177,6 +179,13 @@ _integrate_donated = jax.jit(_integrate_impl,
 _INTEGRATE_JITS = {False: _integrate_sharded, True: _integrate_donated}
 
 
+def _device_count(devices: Optional[int]) -> int:
+    """Devices a launch shards over: all visible ones by default, never
+    more than are visible."""
+    return (jax.device_count() if devices is None
+            else max(1, min(int(devices), jax.device_count())))
+
+
 def _device_plan(span_cells: int, devices: Optional[int]) -> Tuple[int, int]:
     """Device count + padded lane width for one launch span.
 
@@ -190,8 +199,7 @@ def _device_plan(span_cells: int, devices: Optional[int]) -> Tuple[int, int]:
     and 6 host devices).  Pad lanes are frozen at step 0 (budget 0) and
     trimmed before any reduction, so crossing rows stay bit-identical to
     the 1-device launch."""
-    n = (jax.device_count() if devices is None
-         else max(1, min(int(devices), jax.device_count())))
+    n = _device_count(devices)
     tiles = -(-span_cells // CELL_TILE)
     from repro.launch.sharding import plan_cell_tiles
 
@@ -674,7 +682,35 @@ def run_campaign(
       checkpoints, and steals claims older than ``mesh.claim_ttl_s`` from
       dead peers.  Requires ``use_cache`` (the store is the rendezvous);
       every process returns the identical assembled result.
+
+    The campaign runs inside the host span ``repro.campaign.run`` (attributes
+    ``lanes``, ``launches``, ``devices``, ``seed``), its stages inside the
+    spans DESIGN.md §15 lists.
     """
+    n_slices = grid.n_corners * len(grid.temperatures)
+    n_launches = len(_launch_spans(n_slices, bucket_cells(grid.cells),
+                                   max_cells_per_launch))
+    n_dev = _device_count(mesh.n_devices if mesh is not None else devices)
+    with telemetry.span("campaign.run", lanes=n_slices * grid.cells,
+                        launches=n_launches, devices=n_dev, seed=grid.seed):
+        return _run_campaign(
+            p, grid, backend=backend, use_cache=use_cache,
+            cache_dir=cache_dir, devices=devices, chunk=chunk,
+            max_cells_per_launch=max_cells_per_launch, horizon=horizon,
+            checkpoint=checkpoint, max_retries=max_retries,
+            retry_backoff_s=retry_backoff_s,
+            on_slice_complete=on_slice_complete, reduce=reduce,
+            n_bins=n_bins, donate=donate, mesh=mesh)
+
+
+def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
+                  use_cache: bool, cache_dir: Optional[str],
+                  devices: Optional[int], chunk: int,
+                  max_cells_per_launch: Optional[int], horizon: str,
+                  checkpoint: Optional[bool], max_retries: int,
+                  retry_backoff_s: float, on_slice_complete, reduce: str,
+                  n_bins: int, donate: bool, mesh) -> CampaignResult:
+    """``run_campaign`` inside its ``repro.campaign.run`` span."""
     assert backend in ("pallas", "ref"), backend
     assert reduce in ("dense", "stream"), reduce
     streaming = reduce == "stream"
@@ -714,14 +750,15 @@ def run_campaign(
 
     def _load_whole():
         """This mode's durable whole-campaign entry, or None on miss."""
-        if streaming:
-            hit = _cache.load_arrays(red_key, cache_dir)
-            if (hit is not None and "wer" in hit and "hist" in hit
-                    and hit["wer"].shape == expect_wer
-                    and hit["hist"].shape == expect_hist):
-                return hit
-            return None
-        hit = _cache.load(key, cache_dir)
+        with telemetry.span("campaign.cache_load"):
+            if streaming:
+                hit = _cache.load_arrays(red_key, cache_dir)
+                if (hit is not None and "wer" in hit and "hist" in hit
+                        and hit["wer"].shape == expect_wer
+                        and hit["hist"].shape == expect_hist):
+                    return hit
+                return None
+            hit = _cache.load(key, cache_dir)
         return hit if (hit is not None and hit.shape == expect_shape) else None
 
     if use_cache:
@@ -742,18 +779,18 @@ def run_campaign(
         again when a donated launch consumed the block before a retry
         (the draws are deterministic, so a rebuilt block is bit-identical
         to the consumed one)."""
-        if spec is None:
-            st, sd, sg, bd, sp = pack_campaign(grid, p)
-            lp = None
-        else:
-            st, sd, sg, bd, lp, sp = pack_variation(grid, p)
+        with telemetry.span("campaign.pack"):
+            if spec is None:
+                st, sd, sg, bd, sp = pack_campaign(grid, p)
+                lp = None
+            else:
+                st, sd, sg, bd, lp, sp = pack_variation(grid, p)
         return st, sd, sg, bd, lp, sp
 
     def _bucket_pad(st, sd, sg, bd, lp):
         # total-plane pow2 bucket: corner count reaches the compile key
         # only through this logarithmic bucket (3 vs 4 corners usually
         # share a compiled shape; pinned by tests/test_variation.py)
-        from repro.campaign.grid import bucket_cells
         total = st.shape[1]
         pad = bucket_cells(total) - total
         if pad:
@@ -800,52 +837,60 @@ def run_campaign(
                        backend=backend, n_dev=n_dev, chunk=int(chunk))
         return c0, c1, plan_cols, statics
 
-    def compile_launch(a: int, b: int) -> None:
-        """Compile one launch's program before it is dispatched.  A
+    def compile_launch(i: int) -> None:
+        """Compile launch ``i``'s program before it is dispatched.  A
         compiler refusal is deterministic, so it raises here, outside every
         retry ladder; the dispatch then reuses the compiled executable
         (repeat calls hit the jit caches)."""
-        _, _, cols, statics = launch_plan(a, b)
+        _, _, cols, statics = launch_plan(*launches[i])
         shapes = [jax.ShapeDtypeStruct(x.shape[:-1] + (cols,), x.dtype)
                   for x in (state, seeds, sigma, budget)]
         lp = (None if lane_params is None else
               jax.ShapeDtypeStruct((lane_params.shape[0], cols),
                                    lane_params.dtype))
-        _INTEGRATE_JITS[donate].lower(*shapes, lp, **statics).compile()
+        with telemetry.span("campaign.compile", launch=i):
+            _INTEGRATE_JITS[donate].lower(*shapes, lp, **statics).compile()
 
-    def dispatch(a: int, b: int):
-        c0, c1, plan_cols, statics = launch_plan(a, b)
-        st, sd, sg, bd, lp = _pad_lanes(
-            state[:, c0:c1], seeds[c0:c1], sigma[c0:c1], budget[c0:c1],
-            None if lane_params is None else lane_params[:, c0:c1],
-            plan_cols - (c1 - c0), p)
-        fn = _integrate_donated if donate else _integrate_sharded
-        out = fn(st, sd, sg, bd, lp, **statics)
-        if not streaming:
-            return out
-        return _reduce_rows(out, kmin_dev, n_slices=b - a,
-                            slice_cells=slice_cells, n_v=n_v, n_s=n_s,
-                            n_steps=n_steps, n_bins=int(n_bins))
+    def dispatch(i: int):
+        a, b = launches[i]
+        with telemetry.span("campaign.dispatch", launch=i):
+            c0, c1, plan_cols, statics = launch_plan(a, b)
+            st, sd, sg, bd, lp = _pad_lanes(
+                state[:, c0:c1], seeds[c0:c1], sigma[c0:c1], budget[c0:c1],
+                None if lane_params is None else lane_params[:, c0:c1],
+                plan_cols - (c1 - c0), p)
+            fn = _integrate_donated if donate else _integrate_sharded
+            out = fn(st, sd, sg, bd, lp, **statics)
+            if streaming:
+                out = _reduce_rows(out, kmin_dev, n_slices=b - a,
+                                   slice_cells=slice_cells, n_v=n_v, n_s=n_s,
+                                   n_steps=n_steps, n_bins=int(n_bins))
+        telemetry.count("campaign.launches")
+        telemetry.count("campaign.lanes", (b - a) * grid.cells)
+        return out
 
     host_bytes = 0
     n_computed = 0
 
-    def _fetch(out, a: int, b: int) -> Dict[str, np.ndarray]:
-        """Sync one launch and pull its payload to host — the ONLY
+    def _fetch(out, i: int) -> Dict[str, np.ndarray]:
+        """Sync launch ``i`` and pull its payload to host — the ONLY
         device-to-host transfer of the campaign, which ``host_bytes``
         meters (dense: the full (8, cells) block; streaming: the reduced
         counts + histogram, O(grid points))."""
         nonlocal host_bytes
-        c0, c1 = span_cols(a, b)
-        if streaming:
-            wer_d, hist_d = out
-            wer = np.asarray(jax.block_until_ready(wer_d))
-            hist = np.asarray(jax.block_until_ready(hist_d))
-            host_bytes += wer.nbytes + hist.nbytes
-            return {"wer": wer, "hist": hist}
-        blk = np.asarray(jax.block_until_ready(out))
-        host_bytes += blk.nbytes
-        return {"row7": blk[7][: c1 - c0]}   # trim any device-plan pad
+        c0, c1 = span_cols(*launches[i])
+        nbytes = sum(x.nbytes for x in (out if streaming else (out,)))
+        with telemetry.span("campaign.sync", launch=i, bytes=nbytes):
+            if streaming:
+                wer_d, hist_d = out
+                payload = {"wer": np.asarray(jax.block_until_ready(wer_d)),
+                           "hist": np.asarray(jax.block_until_ready(hist_d))}
+            else:
+                blk = np.asarray(jax.block_until_ready(out))
+                payload = {"row7": blk[7][: c1 - c0]}   # trim device-plan pad
+        host_bytes += nbytes
+        telemetry.count("campaign.host_bytes", nbytes)
+        return payload
 
     def _payload_ok(hit, a: int, b: int) -> bool:
         if hit is None:
@@ -858,19 +903,27 @@ def run_campaign(
         return "row7" in hit and hit["row7"].shape == (c1 - c0,)
 
     def _store_slice(a: int, b: int, payload) -> None:
-        _cache.store_arrays(
-            _slice_key(key, a, b, chunk, horizon, skind), payload,
-            header={"campaign": key, "span": [int(a), int(b)],
-                    "kind": skind},
-            cache_dir=cache_dir)
+        with telemetry.span("campaign.cache_store"):
+            _cache.store_arrays(
+                _slice_key(key, a, b, chunk, horizon, skind), payload,
+                header={"campaign": key, "span": [int(a), int(b)],
+                        "kind": skind},
+                cache_dir=cache_dir)
 
-    def _compute(a: int, b: int, out=None) -> Dict[str, np.ndarray]:
-        """Dispatch (if not already in flight) + sync one launch, with the
+    def _load_slice(i: int):
+        a, b = launches[i]
+        with telemetry.span("campaign.cache_load", launch=i):
+            hit = _cache.load_arrays(
+                _slice_key(key, a, b, chunk, horizon, skind), cache_dir)
+        return hit if _payload_ok(hit, a, b) else None
+
+    def _compute(i: int, out=None) -> Dict[str, np.ndarray]:
+        """Dispatch (if not already in flight) + sync launch ``i``, with the
         retry ladder.  Donation can have consumed the packed inputs by the
         time a retry needs them — detected via ``is_deleted`` and repaired
         by re-packing (bit-identical by construction)."""
         nonlocal state, seeds, sigma, budget, lane_params, n_computed
-        compile_launch(a, b)
+        compile_launch(i)
         attempt = 0
         while True:
             try:
@@ -882,8 +935,8 @@ def run_campaign(
                             state, seeds, sigma, budget, lane_params = (
                                 _bucket_pad(state, seeds, sigma, budget,
                                             lane_params))
-                    out = dispatch(a, b)
-                payload = _fetch(out, a, b)
+                    out = dispatch(i)
+                payload = _fetch(out, i)
                 n_computed += 1
                 return payload
             except Exception:
@@ -908,21 +961,20 @@ def run_campaign(
         outs: List[Optional[object]] = [None] * len(launches)
         for i, (a, b) in enumerate(launches):
             if ckpt:
-                hit = _cache.load_arrays(
-                    _slice_key(key, a, b, chunk, horizon, skind), cache_dir)
-                if _payload_ok(hit, a, b):
+                hit = _load_slice(i)
+                if hit is not None:
                     payloads[i] = hit
                     n_resumed += 1
                     continue
-            compile_launch(a, b)
+            compile_launch(i)
             try:
-                outs[i] = dispatch(a, b)
+                outs[i] = dispatch(i)
             except Exception:                # retried in the sync loop
                 outs[i] = None
         for i, (a, b) in enumerate(launches):
             if payloads[i] is not None:
                 continue
-            payloads[i] = _compute(a, b, out=outs[i])
+            payloads[i] = _compute(i, out=outs[i])
             if ckpt:
                 _store_slice(a, b, payloads[i])
             if on_slice_complete is not None:
@@ -931,6 +983,17 @@ def run_campaign(
         owner = f"proc{mesh.process_index}"
         skeys = [_slice_key(key, a, b, chunk, horizon, skind)
                  for a, b in launches]
+
+        def _claim(i: int, steal: bool = False) -> bool:
+            with telemetry.span("campaign.cache_claim", launch=i):
+                if steal:
+                    return _cache.steal_claim(skeys[i], mesh.claim_ttl_s,
+                                              cache_dir, owner=owner)
+                return _cache.try_claim(skeys[i], cache_dir, owner=owner)
+
+        def _release(i: int) -> None:
+            with telemetry.span("campaign.cache_claim", launch=i):
+                _cache.release_claim(skeys[i], cache_dir)
 
         def _claim_and_run(i: int) -> None:
             # holding the claim, re-check the whole-campaign entry: a peer
@@ -941,16 +1004,15 @@ def run_campaign(
             nonlocal whole
             whole = _load_whole()
             if whole is not None:
-                _cache.release_claim(skeys[i], cache_dir)
+                _release(i)
                 return
-            a, b = launches[i]
             try:
-                payload = _compute(a, b)
+                payload = _compute(i)
             except Exception:
-                _cache.release_claim(skeys[i], cache_dir)
+                _release(i)
                 raise
-            _store_slice(a, b, payload)
-            _cache.release_claim(skeys[i], cache_dir)
+            _store_slice(*launches[i], payload)
+            _release(i)
             payloads[i] = payload
             if on_slice_complete is not None:
                 on_slice_complete(i, len(launches))
@@ -964,12 +1026,11 @@ def run_campaign(
             if whole is not None:
                 break
             i = (start + j) % len(launches)
-            a, b = launches[i]
-            hit = _cache.load_arrays(skeys[i], cache_dir)
-            if _payload_ok(hit, a, b):
+            hit = _load_slice(i)
+            if hit is not None:
                 payloads[i] = hit
                 n_resumed += 1
-            elif _cache.try_claim(skeys[i], cache_dir, owner=owner):
+            elif _claim(i):
                 _claim_and_run(i)
 
         # pass B: poll the store for peers' slices; steal claims older
@@ -982,18 +1043,17 @@ def run_campaign(
             whole = _load_whole()
             if whole is not None:
                 break
-            for i, (a, b) in enumerate(launches):
+            for i in range(len(launches)):
                 if whole is not None or payloads[i] is not None:
                     continue
-                hit = _cache.load_arrays(skeys[i], cache_dir)
-                if _payload_ok(hit, a, b):
+                hit = _load_slice(i)
+                if hit is not None:
                     payloads[i] = hit
                     n_resumed += 1
                 elif _cache.claim_age_s(skeys[i], cache_dir) is None:
-                    if _cache.try_claim(skeys[i], cache_dir, owner=owner):
+                    if _claim(i):
                         _claim_and_run(i)
-                elif _cache.steal_claim(skeys[i], mesh.claim_ttl_s,
-                                        cache_dir, owner=owner):
+                elif _claim(i, steal=True):
                     _claim_and_run(i)
             if whole is None and any(pl is None for pl in payloads):
                 if time.time() > deadline:
@@ -1013,56 +1073,62 @@ def run_campaign(
         return CampaignResult(grid=grid, backend=backend,
                               crossing_time=whole, **common)
 
-    if streaming:
-        wer_cat = np.concatenate([pl["wer"] for pl in payloads])
-        hist_cat = np.concatenate([pl["hist"] for pl in payloads])
-        if spec is not None:
-            wer_cat = wer_cat.reshape(n_c, n_t, n_v, n_p)
-            hist_cat = hist_cat.reshape(n_c, n_t, n_v, int(n_bins))
-        if use_cache:
-            _cache.store_arrays(
-                red_key, {"wer": wer_cat, "hist": hist_cat},
-                header={"campaign": key, "kind": "reduced",
-                        "n_bins": int(n_bins), "backend": backend},
-                cache_dir=cache_dir)
-        if ckpt:
-            for a, b in launches:
-                _cache.drop_arrays(
-                    _slice_key(key, a, b, chunk, horizon, skind), cache_dir)
-        return _reduced_result(wer_cat, hist_cat, elapsed_s=elapsed,
-                               n_launches=len(launches),
-                               n_resumed=n_resumed, host_bytes=host_bytes,
-                               n_computed=n_computed)
-
-    # clip the quantized-horizon sentinel (n_static) back to the grid's
-    # horizon: real crossings are <= budget == n_steps and pass unchanged.
-    # float64 before the dt multiply — in f32 the sentinel n_steps*dt
-    # rounds below the f64 horizon and never-crossed lanes would leak into
-    # the switched-only latency reductions
-    row7 = np.minimum(
-        np.concatenate([pl["row7"] for pl in payloads]).astype(np.float64),
-        float(n_steps))
-    crossing = np.empty(expect_shape)
-    for si, (lo, hi) in enumerate(spans):
-        plane = row7[lo:hi].reshape(n_v, n_s) * grid.dt
-        if spec is None:
-            crossing[si] = plane
+    with telemetry.span("campaign.assemble"):
+        if streaming:
+            wer_cat = np.concatenate([pl["wer"] for pl in payloads])
+            hist_cat = np.concatenate([pl["hist"] for pl in payloads])
+            if spec is not None:
+                wer_cat = wer_cat.reshape(n_c, n_t, n_v, n_p)
+                hist_cat = hist_cat.reshape(n_c, n_t, n_v, int(n_bins))
+            if use_cache:
+                with telemetry.span("campaign.cache_store"):
+                    _cache.store_arrays(
+                        red_key, {"wer": wer_cat, "hist": hist_cat},
+                        header={"campaign": key, "kind": "reduced",
+                                "n_bins": int(n_bins), "backend": backend},
+                        cache_dir=cache_dir)
+            result = _reduced_result(wer_cat, hist_cat, elapsed_s=elapsed,
+                                     n_launches=len(launches),
+                                     n_resumed=n_resumed,
+                                     host_bytes=host_bytes,
+                                     n_computed=n_computed)
         else:
-            crossing[si // n_t, si % n_t] = plane
-
-    if use_cache:
-        _cache.store(key, crossing,
-                     header={"params": dataclasses.asdict(p),
-                             "grid": dataclasses.asdict(grid),
-                             "backend": backend},
-                     cache_dir=cache_dir)
-    if ckpt:
-        # the whole-campaign entry is durable (or caching is off and the
-        # result is in hand) — retire the per-slice resume checkpoints
-        for a, b in launches:
-            _cache.drop_arrays(_slice_key(key, a, b, chunk, horizon, skind),
-                               cache_dir)
-    return CampaignResult(grid=grid, backend=backend, crossing_time=crossing,
-                          elapsed_s=elapsed, n_launches=len(launches),
-                          n_resumed=n_resumed, host_bytes=host_bytes,
-                          n_computed=n_computed)
+            # clip the quantized-horizon sentinel (n_static) back to the
+            # grid's horizon: real crossings are <= budget == n_steps and
+            # pass unchanged.  float64 before the dt multiply — in f32 the
+            # sentinel n_steps*dt rounds below the f64 horizon and
+            # never-crossed lanes would leak into the switched-only
+            # latency reductions
+            row7 = np.minimum(
+                np.concatenate([pl["row7"] for pl in payloads]
+                               ).astype(np.float64),
+                float(n_steps))
+            crossing = np.empty(expect_shape)
+            for si, (lo, hi) in enumerate(spans):
+                plane = row7[lo:hi].reshape(n_v, n_s) * grid.dt
+                if spec is None:
+                    crossing[si] = plane
+                else:
+                    crossing[si // n_t, si % n_t] = plane
+            if use_cache:
+                with telemetry.span("campaign.cache_store"):
+                    _cache.store(key, crossing,
+                                 header={"params": dataclasses.asdict(p),
+                                         "grid": dataclasses.asdict(grid),
+                                         "backend": backend},
+                                 cache_dir=cache_dir)
+            result = CampaignResult(
+                grid=grid, backend=backend, crossing_time=crossing,
+                elapsed_s=elapsed, n_launches=len(launches),
+                n_resumed=n_resumed, host_bytes=host_bytes,
+                n_computed=n_computed)
+        if ckpt:
+            # the whole-campaign entry is durable (or caching is off and
+            # the result is in hand) — retire the per-slice resume
+            # checkpoints
+            with telemetry.span("campaign.cache_store"):
+                for a, b in launches:
+                    _cache.drop_arrays(
+                        _slice_key(key, a, b, chunk, horizon, skind),
+                        cache_dir)
+    return result

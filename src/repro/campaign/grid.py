@@ -46,6 +46,7 @@ from repro.core.params import DeviceParams, VariationSpec
 from repro.kernels import noise
 from repro.kernels.llg_rk4 import CELL_TILE
 from repro.kernels.ops import pack_states
+from repro.runtime import telemetry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -267,17 +268,19 @@ def pack_campaign(grid: CampaignGrid, p: DeviceParams):
     states, seed_rows, sigma_rows, budget_rows, spans = [], [], [], [], []
     offset = 0
     for ti, temp in enumerate(grid.temperatures):
-        p_t = (p if temp == p.temperature
-               else dataclasses.replace(p, temperature=float(temp)))
-        st, sd = pack_plane(grid, p_t, ti)
-        padded = st.shape[1]
-        lane = jnp.arange(padded)
-        states.append(st)
-        seed_rows.append(sd)
-        sigma_rows.append(jnp.full((padded,), thermal_sigma(p_t, grid.dt),
-                                   jnp.float32))
-        budget_rows.append(
-            jnp.where(lane < grid.cells, n_steps, 0.0).astype(jnp.float32))
+        with telemetry.span("campaign.pack_slice", slice=ti):
+            p_t = (p if temp == p.temperature
+                   else dataclasses.replace(p, temperature=float(temp)))
+            st, sd = pack_plane(grid, p_t, ti)
+            padded = st.shape[1]
+            lane = jnp.arange(padded)
+            states.append(st)
+            seed_rows.append(sd)
+            sigma_rows.append(jnp.full((padded,),
+                                       thermal_sigma(p_t, grid.dt),
+                                       jnp.float32))
+            budget_rows.append(
+                jnp.where(lane < grid.cells, n_steps, 0.0).astype(jnp.float32))
         spans.append((offset, offset + grid.cells))
         offset += padded
     return (jnp.concatenate(states, axis=1),
@@ -317,32 +320,33 @@ def pack_variation(grid: CampaignGrid, p: DeviceParams):
     states, seed_rows, sigma_rows, budget_rows, lane_rows_, spans = (
         [], [], [], [], [], [])
     offset = 0
-    for corner in spec.corners:
+    for ci, corner in enumerate(spec.corners):
         for ti, temp in enumerate(grid.temperatures):
-            rows = spec.lane_rows(p, corner, cells, grid.dt,
-                                  temperature=temp, stream=ti)
-            zs, ph = _plane_tilt_draws(grid, ti, cells)
-            th = zs * jnp.asarray(rows.theta0, jnp.float32) + 0.01
-            m0 = jax.vmap(lambda t, f: llg.initial_state(p, t, f))(th, ph)
-            v = jnp.repeat(jnp.asarray(grid.voltages, jnp.float32),
-                           grid.n_samples)
-            st = pack_soa(m0, v)
-            padded = st.shape[1]
-            pad = padded - cells
+            with telemetry.span("campaign.pack_slice", slice=ci * n_t + ti):
+                rows = spec.lane_rows(p, corner, cells, grid.dt,
+                                      temperature=temp, stream=ti)
+                zs, ph = _plane_tilt_draws(grid, ti, cells)
+                th = zs * jnp.asarray(rows.theta0, jnp.float32) + 0.01
+                m0 = jax.vmap(lambda t, f: llg.initial_state(p, t, f))(th, ph)
+                v = jnp.repeat(jnp.asarray(grid.voltages, jnp.float32),
+                               grid.n_samples)
+                st = pack_soa(m0, v)
+                padded = st.shape[1]
+                pad = padded - cells
 
-            def _row(vals, fill):
-                return np.pad(np.asarray(vals, np.float64), (0, pad),
-                              constant_values=fill).astype(np.float32)
+                def _row(vals, fill):
+                    return np.pad(np.asarray(vals, np.float64), (0, pad),
+                                  constant_values=fill).astype(np.float32)
 
-            states.append(st)
-            seed_rows.append(noise.slice_seeds(grid.seed, ti, padded))
-            sigma_rows.append(_row(rows.sigma, 0.0))
-            budget_rows.append(_row(np.full(cells, n_steps), 0.0))
-            lane_rows_.append(np.stack([
-                _row(rows.alpha, p.alpha),
-                _row(rows.b_aniso, p.b_aniso),
-                _row(rows.g_scale, 1.0),
-            ]))
+                states.append(st)
+                seed_rows.append(noise.slice_seeds(grid.seed, ti, padded))
+                sigma_rows.append(_row(rows.sigma, 0.0))
+                budget_rows.append(_row(np.full(cells, n_steps), 0.0))
+                lane_rows_.append(np.stack([
+                    _row(rows.alpha, p.alpha),
+                    _row(rows.b_aniso, p.b_aniso),
+                    _row(rows.g_scale, 1.0),
+                ]))
             spans.append((offset, offset + cells))
             offset += padded
     return (jnp.concatenate(states, axis=1),

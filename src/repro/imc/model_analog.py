@@ -66,6 +66,7 @@ from repro.kernels.fake_analog import (ROW_ATT_NEG, ROW_ATT_POS, ROW_DECODE,
 from repro.kernels.ops import _default_interpret
 from repro.models import model as model_mod
 from repro.models.common import intercept_linears, rms_norm
+from repro.runtime import telemetry
 
 # bumped when the programming chain changes numerically — stale cache
 # entries then simply never match (same policy as campaign KERNEL_VERSION)
@@ -182,8 +183,10 @@ def _fake_mvm_body(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
                    adc_bits: int, apply_fet: bool, use_fail: bool,
                    ir_drop: bool, has_imax: bool, decode: bool,
                    interpret: bool, use_faults: bool = False,
-                   repair: Optional[RepairPolicy] = None):
-    """Traced fake-analog ``x @ w``: operand preamble + fused kernel."""
+                   repair: Optional[RepairPolicy] = None,
+                   name: str = "fake_analog"):
+    """Traced fake-analog ``x @ w``: operand preamble + fused kernel, the
+    kernel named ``name`` in the program and its traces."""
     v, wn, fail, aux = _fake_operands(
         x, w, bl, scal, apply_fet=apply_fet, use_fail=use_fail,
         ir_drop=ir_drop, has_imax=has_imax, decode=decode,
@@ -191,7 +194,7 @@ def _fake_mvm_body(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
     return fake_analog_mac_pallas(v, wn, fail, aux, adc_bits=adc_bits,
                                   apply_fet=apply_fet,
                                   use_fail=use_fail or use_faults,
-                                  interpret=interpret)
+                                  interpret=interpret, name=name)
 
 
 @functools.lru_cache(maxsize=None)
@@ -399,7 +402,9 @@ def program_weights_cached(
 def _forward_unrolled(params, cfg: ArchConfig, tokens: jnp.ndarray):
     """Full-sequence logits via an eager layer unroll (no lax.scan — the
     device-path hook reduces to host floats, which cannot cross a scan).
-    Decoder-only: same blocks as ``forward_train``, full logits returned."""
+    Decoder-only: same blocks as ``forward_train``, full logits returned.
+    Device ops carry their layer in ``op_name``: ``block{i}/attn``,
+    ``block{i}/ffn`` and ``unembed`` (the output head)."""
     assert cfg.n_encoder_layers == 0, "analog routing covers decoder-only"
     x = model_mod._embed(params, cfg, tokens)
     B, S, _ = x.shape
@@ -407,10 +412,12 @@ def _forward_unrolled(params, cfg: ArchConfig, tokens: jnp.ndarray):
     for rep in range(cfg.n_pattern_repeats):
         lp = jax.tree_util.tree_map(lambda a: a[rep], params["blocks"])
         for i, (mixer, f) in enumerate(cfg.pattern):
-            x, _ = model_mod._run_block(lp[f"pos{i}"], x, cfg, mixer, f,
-                                        positions)
+            with jax.named_scope(f"block{rep * len(cfg.pattern) + i}"):
+                x, _ = model_mod._run_block(lp[f"pos{i}"], x, cfg, mixer, f,
+                                            positions)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return model_mod._logits(params, cfg, x)
+    with jax.named_scope("unembed"):
+        return model_mod._logits(params, cfg, x)
 
 
 def model_forward_logits(params, cfg: ArchConfig, tokens, hook=None):
@@ -446,9 +453,12 @@ def _jitted_fake_forward(cfg: ArchConfig, adc_bits: int, apply_fet: bool,
     def run(params, tokens, scal):
         # rows = K of each site, like the device path's per-layer
         # BitlineParams — shapes are static at trace time, so every site
-        # bakes its own IR line length into the one executable
+        # bakes its own IR line length into the one executable; each
+        # kernel is named after its site (``fake_analog_unembed``), which
+        # a device trace shows
         def hook(x2, w, tag):
-            return body(x2, w, BitlineParams(rows=w.shape[0]), scal)
+            return body(x2, w, BitlineParams(rows=w.shape[0]), scal,
+                        name=f"fake_analog_{tag}" if tag else "fake_analog")
 
         with intercept_linears(hook):
             return _forward_unrolled(params, cfg, tokens)
@@ -477,17 +487,25 @@ def analog_model_logits(
     cache_dir: Optional[str] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Full-sequence logits with every linear routed through the analog MVM."""
+    """Full-sequence logits with every linear routed through the analog MVM.
+    The fake path runs inside the host spans ``repro.analog.prepare`` and
+    ``repro.analog.dispatch`` (DESIGN.md §15)."""
     interp = _default_interpret() if interpret is None else interpret
     if mode == "fake":
-        apply_fet, g_scale = _systematic_g_scale(acfg)
-        fn = _jitted_fake_forward(cfg, acfg.adc_bits, apply_fet,
-                                  acfg.write_ber > 0.0, acfg.ir_drop, interp,
-                                  _fake_faults_mode(acfg), acfg.repair)
-        # device constants are rows-independent (the FET series combination
-        # has no wire term), so one scalar pack serves every layer
-        scal = _fake_scalars(kind, acfg, BitlineParams(), g_scale, None)
-        return fn(params, tokens, scal)
+        with telemetry.span("analog.prepare"):
+            apply_fet, g_scale = _systematic_g_scale(acfg)
+            fn = _jitted_fake_forward(cfg, acfg.adc_bits, apply_fet,
+                                      acfg.write_ber > 0.0, acfg.ir_drop,
+                                      interp, _fake_faults_mode(acfg),
+                                      acfg.repair)
+            # device constants are rows-independent (the FET series
+            # combination has no wire term), so one scalar pack serves
+            # every layer
+            scal = _fake_scalars(kind, acfg, BitlineParams(), g_scale, None)
+        batch, seq = jnp.shape(tokens)
+        with telemetry.span("analog.dispatch", batch=batch, seq=seq,
+                            adc_bits=acfg.adc_bits):
+            return fn(params, tokens, scal)
     if mode == "bnn":
         return _jitted_bnn_forward(cfg, tie, interp)(params, tokens)
     if mode == "device":

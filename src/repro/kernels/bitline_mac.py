@@ -100,6 +100,7 @@ def bitline_mac_pallas(
         out_specs=pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)],
         interpret=interpret,
+        name="bitline_mac",
     )(v, g)
     if (mp, np_) != (M, N):
         out = out[:M, :N]

@@ -156,6 +156,7 @@ def fake_analog_mac_pallas(
     apply_fet: bool = False,
     use_fail: bool = False,
     interpret: bool = False,
+    name: str = "fake_analog",    # the kernel's name in the program's HLO
 ) -> jnp.ndarray:
     M, K = v.shape
     K2, N = wn.shape
@@ -187,6 +188,7 @@ def fake_analog_mac_pallas(
         out_specs=pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(v, wn, fail, aux)
     if (mp, np_) != (M, N):
         out = out[:M, :N]

@@ -294,6 +294,7 @@ def llg_rk4_pallas(
             in_specs=[pl.BlockSpec((ROWS, CELL_TILE), lambda i: (0, i))],
             out_specs=pl.BlockSpec((ROWS, CELL_TILE), lambda i: (0, i)),
             interpret=interpret,
+            name="llg_rk4_deterministic",
         )(state)
 
     seeds = seeds.reshape(1, cells).astype(jnp.uint32)
@@ -329,4 +330,5 @@ def llg_rk4_pallas(
         ],
         out_specs=pl.BlockSpec((ROWS, CELL_TILE), lambda i: (0, i)),
         interpret=interpret,
+        name="llg_rk4",
     )(state, seeds, aux)

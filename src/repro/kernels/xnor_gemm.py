@@ -84,6 +84,7 @@ def xnor_gemm_pallas(
         out_specs=pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)],
         interpret=interpret,
+        name="xnor_gemm",
     )(a, w)
     if (mp, np_) != (M, N):
         out = out[:M, :N]

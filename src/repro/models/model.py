@@ -126,25 +126,28 @@ def _run_block(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full-sequence block (train/prefill).  Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if mixer.startswith("attn"):
-        y = attn.self_attention(p["attn"], h, cfg, positions, mixer)
-    else:
-        y = ssm_mod.mamba_forward(p["mamba"], h, cfg)
-    x = x + _maybe_post(p, "post_ln1", y, cfg)
-    x = constrain(x, "act_btd")
+    # named scopes put the sub-layer into every device op's ``op_name``
+    with jax.named_scope("attn" if mixer.startswith("attn") else "ssm"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        if mixer.startswith("attn"):
+            y = attn.self_attention(p["attn"], h, cfg, positions, mixer)
+        else:
+            y = ssm_mod.mamba_forward(p["mamba"], h, cfg)
+        x = x + _maybe_post(p, "post_ln1", y, cfg)
+        x = constrain(x, "act_btd")
     if mem_kv is not None:
         h = rms_norm(x, p["ln_cross"], cfg.norm_eps)
         x = x + attn.cross_attention(p["cross"], h, mem_kv[0], mem_kv[1], cfg)
     if ffn != "none":
-        h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if ffn == "moe":
-            y, a = ffn_mod.moe_ffn(p["ffn"], h, cfg)
-            aux = aux + a
-        else:
-            y = ffn_mod.dense_ffn(p["ffn"], h, cfg)
-        x = x + _maybe_post(p, "post_ln2", y, cfg)
-        x = constrain(x, "act_btd")
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, p["ln2"], cfg.norm_eps)
+            if ffn == "moe":
+                y, a = ffn_mod.moe_ffn(p["ffn"], h, cfg)
+                aux = aux + a
+            else:
+                y = ffn_mod.dense_ffn(p["ffn"], h, cfg)
+            x = x + _maybe_post(p, "post_ln2", y, cfg)
+            x = constrain(x, "act_btd")
     return x, aux
 
 
