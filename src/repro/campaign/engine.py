@@ -773,6 +773,17 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
                                   from_cache=True, n_launches=0)
 
     n_static = _quantize_steps(n_steps, horizon) if chunk > 0 else n_steps
+    n_slices = n_c * n_t
+    slice_cells = bucket_cells(grid.cells)
+    launches = _launch_spans(n_slices, slice_cells, max_cells_per_launch)
+    # a single launch whose device plan needs no pad lanes takes the packed
+    # block whole (a full slice is the array itself): pack it straight onto
+    # the launch's devices
+    pack_dev = 1
+    if spec is None and len(launches) == 1:
+        plan_dev, plan_cols = _device_plan(n_slices * slice_cells, devices)
+        if plan_cols == n_slices * slice_cells:
+            pack_dev = plan_dev
 
     def _pack_inputs():
         """(Re-)pack the campaign's device inputs — once up front, and
@@ -781,7 +792,7 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
         to the consumed one)."""
         with telemetry.span("campaign.pack"):
             if spec is None:
-                st, sd, sg, bd, sp = pack_campaign(grid, p)
+                st, sd, sg, bd, sp = pack_campaign(grid, p, n_dev=pack_dev)
                 lp = None
             else:
                 st, sd, sg, bd, lp, sp = pack_variation(grid, p)
@@ -805,9 +816,6 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
         return st, sd, sg, bd, lp
 
     state, seeds, sigma, budget, lane_params, spans = _pack_inputs()
-    n_slices = n_c * n_t
-    slice_cells = state.shape[1] // n_slices
-    launches = _launch_spans(n_slices, slice_cells, max_cells_per_launch)
     single_variation = spec is not None and len(launches) == 1
     if single_variation:
         state, seeds, sigma, budget, lane_params = _bucket_pad(

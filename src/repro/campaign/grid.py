@@ -33,15 +33,17 @@ Key packing insights (DESIGN.md §8):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import llg
-from repro.core.device import thermal_theta0
+from repro.core.device import theta0_of_stability
 from repro.core.params import DeviceParams, VariationSpec
 from repro.kernels import noise
 from repro.kernels.llg_rk4 import CELL_TILE
@@ -210,40 +212,102 @@ def pack_plane(grid: CampaignGrid, p: DeviceParams, t_index: int):
 
     Initial states follow ``core.montecarlo``: |N(0,1)| * theta_eq + 0.01
     tilt, uniform azimuth — the Boltzmann spread of the idle cell.  The tilt
-    RNG is ``jax.random`` off ``grid.seed`` (host-side, once per campaign);
-    the *per-step* thermal field streams are counter-RNG seeds derived from
-    ``grid.seed`` and the temperature index so every (T, V, S) lane is an
-    independent realization.
+    RNG is ``jax.random`` off ``grid.seed``; the *per-step* thermal field
+    streams are counter-RNG seeds derived from ``grid.seed`` and the
+    temperature index so every (T, V, S) lane is an independent
+    realization.  This is the one-slice view of ``pack_campaign``'s
+    program: slice ``t_index`` of a campaign whose nominal device is ``p``
+    at that slice's temperature packs to the same bits.
     """
-    n_v, n_s = len(grid.voltages), grid.n_samples
-    cells = n_v * n_s
-    zs, ph = _plane_tilt_draws(grid, t_index, cells)
-    th = zs * thermal_theta0(p) + 0.01
-    m0 = jax.vmap(lambda t, f: llg.initial_state(p, t, f))(th, ph)
-    v = jnp.repeat(jnp.asarray(grid.voltages, jnp.float32), n_s)
-
-    state = pack_soa(m0, v)                         # pads to bucket_cells
-    padded = state.shape[1]
-    # distinct stream block per temperature slice: offset the base seed so
-    # T=0 and T=1 lanes never share counters (kernels.noise.slice_seeds)
-    seeds = noise.slice_seeds(grid.seed, t_index, padded)
+    state, seeds, _, _ = _run_pack(grid, p, [_slice_inputs(grid, p, t_index)])
     return state, seeds
 
 
-def _plane_tilt_draws(grid: CampaignGrid, t_index: int, cells: int):
-    """The Boltzmann tilt normals and azimuths of one (V x S) plane —
-    shared by ``pack_plane`` and the variation packer, so a variation
-    campaign's slices reuse exactly the draws the nominal packing would
-    (the per-lane tilt then differs only through the corner's own
-    ``theta0``: common random numbers across corners)."""
-    key = jax.random.fold_in(jax.random.PRNGKey(grid.seed), t_index)
-    k_th, k_ph = jax.random.split(key)
+def _tilt_draws(key, t_index, cells: int):
+    """The Boltzmann tilt normals and azimuths of one (V x S) plane, off
+    the campaign's threefry ``key`` (``t_index`` may be traced)."""
+    k_th, k_ph = jax.random.split(jax.random.fold_in(key, t_index))
     zs = jnp.abs(jax.random.normal(k_th, (cells,)))
     ph = jax.random.uniform(k_ph, (cells,), maxval=2 * jnp.pi)
     return zs, ph
 
 
-def pack_campaign(grid: CampaignGrid, p: DeviceParams):
+def _plane_tilt_draws(grid: CampaignGrid, t_index: int, cells: int):
+    """``_tilt_draws`` of ``grid.seed`` — shared by the pack program and the
+    variation packer, so a variation campaign's slices reuse exactly the
+    draws the nominal packing would (the per-lane tilt then differs only
+    through the corner's own ``theta0``: common random numbers across
+    corners)."""
+    return _tilt_draws(jax.random.PRNGKey(grid.seed), t_index, cells)
+
+
+def _slice_inputs(grid: CampaignGrid, p: DeviceParams, t_index: int):
+    """Host-side inputs of slice ``t_index`` at device ``p``'s temperature:
+    (slice index, stream base seed, barrier Delta, Brown sigma)."""
+    from repro.core.montecarlo import thermal_sigma
+
+    return (t_index, noise.slice_base(grid.seed, t_index),
+            p.thermal_stability, thermal_sigma(p, grid.dt))
+
+
+@functools.partial(jax.jit, static_argnames=("p", "n_s", "n_dev"))
+def _pack_program(seed, t_index, base, delta, sigma, voltages, n_steps, *,
+                  p: DeviceParams, n_s: int, n_dev: int):
+    """The whole fused ``(state, seeds, sigma, budget)`` block of
+    ``len(t_index)`` temperature slices in one program (DESIGN.md §8).
+
+    Statics fix shapes and code paths only: the nominal device ``p`` (its
+    ``n_sublattices`` picks ``pack_soa``'s branch), the samples per
+    voltage, the slice count and voltage count (through the array
+    shapes), and the devices the block is laid out on.  Seeds, slice
+    indices, per-slice stream bases, Delta and sigma, the voltages and the
+    step budget are traced, so a new campaign of the same shape runs
+    without tracing; ``campaign.pack_traces`` counts the traces.  With
+    ``n_dev > 1`` the block comes out sharded along its lanes as
+    ``_integrate_sharded`` takes it.
+    """
+    telemetry.count("campaign.pack_traces")
+    key = jax.random.PRNGKey(seed)
+    cells = voltages.shape[0] * n_s
+    v = jnp.repeat(voltages, n_s)
+    lane = jnp.arange(bucket_cells(cells))
+    states, seed_rows, sigma_rows, budget_rows = [], [], [], []
+    for i in range(t_index.shape[0]):
+        zs, ph = _tilt_draws(key, t_index[i], cells)
+        # max(., 0) changes no value (both factors are >= 0) but rounds the
+        # product on its own: XLA:CPU would contract the multiply-add into
+        # one FMA, an ulp off the tilt ``pack_variation`` computes eagerly
+        th = jnp.maximum(zs * theta0_of_stability(delta[i]), 0.0) + 0.01
+        m0 = jax.vmap(lambda t, f: llg.initial_state(p, t, f))(th, ph)
+        states.append(pack_soa(m0, v))                  # pads to the bucket
+        seed_rows.append(noise.cell_seeds(base[i], lane.shape[0]))
+        sigma_rows.append(jnp.full(lane.shape, sigma[i]))
+        budget_rows.append(jnp.where(lane < cells, n_steps, 0.0))
+    out = (jnp.concatenate(states, axis=1), jnp.concatenate(seed_rows),
+           jnp.concatenate(sigma_rows), jnp.concatenate(budget_rows))
+    if n_dev > 1:
+        # every device builds the whole block (a few hundred microseconds
+        # of device work) and keeps its own lanes: no collective at all
+        mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("cells",))
+        out = tuple(jax.lax.with_sharding_constraint(
+            jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P())),
+            NamedSharding(mesh, P(*(None,) * (x.ndim - 1), "cells")))
+            for x in out)
+    return out
+
+
+def _run_pack(grid: CampaignGrid, p: DeviceParams, slices, n_dev: int = 1):
+    """Call ``_pack_program`` on the host-side inputs of ``slices``."""
+    t_index, base, delta, sigma = zip(*slices)
+    return _pack_program(
+        np.uint32(grid.seed & 0xFFFFFFFF), np.asarray(t_index, np.int32),
+        np.asarray(base, np.uint32), np.asarray(delta, np.float32),
+        np.asarray(sigma, np.float32),
+        np.asarray(grid.voltages, np.float32), np.float32(grid.n_steps),
+        p=p, n_s=grid.n_samples, n_dev=n_dev)
+
+
+def pack_campaign(grid: CampaignGrid, p: DeviceParams, n_dev: int = 1):
     """Fuse the temperature axis into the cells plane: one SoA block for the
     whole (T x V x S) grid.
 
@@ -256,38 +320,26 @@ def pack_campaign(grid: CampaignGrid, p: DeviceParams):
     becomes a per-lane row (slice ``ti`` carries ``thermal_sigma(p @ T_ti,
     dt)``) and the padded lanes carry a step budget of 0.
 
+    The host derives each slice's scalars (inside its ``pack_slice`` span),
+    then one call of ``_pack_program`` builds the block on the device;
+    ``n_dev > 1`` lays it out over that many devices, lanes sharded.
+
     Returns ``(state, seeds, sigma, budget, spans)``: the ``(8, cells)``
     SoA block, per-lane uint32 streams, per-lane sigma row [T], per-lane
     step-budget row (``grid.n_steps`` on real lanes, 0 on padding), and
     ``spans[ti] = (start, stop)`` — the real-lane slice of temperature
     ``ti`` in the packed plane.
     """
-    from repro.core.montecarlo import thermal_sigma
-
-    n_steps = float(grid.n_steps)
-    states, seed_rows, sigma_rows, budget_rows, spans = [], [], [], [], []
-    offset = 0
+    padded = bucket_cells(grid.cells)
+    slices, spans = [], []
     for ti, temp in enumerate(grid.temperatures):
         with telemetry.span("campaign.pack_slice", slice=ti):
             p_t = (p if temp == p.temperature
                    else dataclasses.replace(p, temperature=float(temp)))
-            st, sd = pack_plane(grid, p_t, ti)
-            padded = st.shape[1]
-            lane = jnp.arange(padded)
-            states.append(st)
-            seed_rows.append(sd)
-            sigma_rows.append(jnp.full((padded,),
-                                       thermal_sigma(p_t, grid.dt),
-                                       jnp.float32))
-            budget_rows.append(
-                jnp.where(lane < grid.cells, n_steps, 0.0).astype(jnp.float32))
-        spans.append((offset, offset + grid.cells))
-        offset += padded
-    return (jnp.concatenate(states, axis=1),
-            jnp.concatenate(seed_rows),
-            jnp.concatenate(sigma_rows),
-            jnp.concatenate(budget_rows),
-            spans)
+            slices.append(_slice_inputs(grid, p_t, ti))
+        spans.append((ti * padded, ti * padded + grid.cells))
+    state, seeds, sigma, budget = _run_pack(grid, p, slices, n_dev)
+    return state, seeds, sigma, budget, spans
 
 
 def pack_variation(grid: CampaignGrid, p: DeviceParams):

@@ -25,7 +25,13 @@ from repro.core.params import DeviceParams, DeviceSample
 # Default thermal tilt of the initial state: theta_0 = sqrt(1/(2 Delta)),
 # the equilibrium Boltzmann spread for a macrospin with barrier Delta kT.
 def thermal_theta0(p: DeviceParams) -> jnp.ndarray:
-    return jnp.sqrt(1.0 / (2.0 * jnp.maximum(p.thermal_stability, 1.0)))
+    return theta0_of_stability(p.thermal_stability)
+
+
+def theta0_of_stability(delta) -> jnp.ndarray:
+    """``thermal_theta0`` from the barrier Delta alone (a float or a traced
+    float32), for programs that take Delta as data."""
+    return jnp.sqrt(1.0 / (2.0 * jnp.maximum(delta, 1.0)))
 
 
 @jax.tree_util.register_dataclass

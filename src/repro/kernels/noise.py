@@ -25,6 +25,7 @@ guarantees to well below the sigma of the physics.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -45,14 +46,18 @@ def mix32(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
-def cell_seeds(base_seed: int, cells: int) -> jnp.ndarray:
+def cell_seeds(base_seed, cells: int) -> jnp.ndarray:
     """(cells,) uint32 — one independent stream seed per cell/lane.
 
     splitmix-style: mix a Weyl sequence off the base seed so consecutive
-    cells land in decorrelated regions of counter space.
+    cells land in decorrelated regions of counter space.  ``base_seed`` is
+    a Python int (low 32 bits used) or a uint32 array scalar, which a
+    jitted caller passes as traced data.
     """
     idx = jnp.arange(cells, dtype=jnp.uint32)
-    return mix32(mix32(np.uint32(base_seed & 0xFFFFFFFF) + idx * _GOLD))
+    base = (base_seed.astype(jnp.uint32) if isinstance(base_seed, jax.Array)
+            else np.uint32(base_seed & 0xFFFFFFFF))
+    return mix32(mix32(base + idx * _GOLD))
 
 
 _SLICE_GOLD = 0x9E3779B1        # odd Weyl constants: campaign seed ...
@@ -67,8 +72,12 @@ def slice_seeds(base_seed: int, slice_index: int, cells: int) -> jnp.ndarray:
     (T x V x S) plane never share counters — and a fused launch consumes
     exactly the streams the old per-temperature launches did (the packing
     bit-compat ``tests/test_fused_engine.py`` pins)."""
-    base = (base_seed * _SLICE_GOLD + slice_index * _SLICE_OFF) & 0xFFFFFFFF
-    return cell_seeds(base, cells)
+    return cell_seeds(slice_base(base_seed, slice_index), cells)
+
+
+def slice_base(base_seed: int, slice_index: int) -> int:
+    """The base seed ``slice_seeds`` splits into lanes, as a host int."""
+    return (base_seed * _SLICE_GOLD + slice_index * _SLICE_OFF) & 0xFFFFFFFF
 
 
 def _uniform24(h: jnp.ndarray) -> jnp.ndarray:

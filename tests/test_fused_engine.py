@@ -22,9 +22,10 @@ from repro.campaign import (CampaignGrid, bucket_cells, pack_campaign,
                             pack_plane, run_campaign, run_ensemble)
 from repro.campaign.engine import _integrate_sharded, brown_sigma
 from repro.core import llg
-from repro.core.params import AFMTJ_PARAMS
+from repro.core.params import AFMTJ_PARAMS, MTJ_PARAMS
 from repro.kernels import noise, ops, ref
 from repro.kernels.llg_rk4 import CELL_TILE
+from repro.runtime import telemetry
 
 TEMPS = (260.0, 300.0, 340.0)
 
@@ -77,6 +78,82 @@ def test_pack_campaign_layout(fused_grid):
         assert (bud[lo + fused_grid.cells:lo + per] == 0.0).all()
     # hotter slices fluctuate harder
     assert sig[0] < sig[-1]
+
+
+# ------------------------------------------------ the jitted pack program
+def _eager_pack_campaign(grid, p):
+    """The pack as it ran before it became one program: eager ``jax.random``
+    and ``jnp`` ops, slice by slice, concatenated on the host side."""
+    from repro.campaign.grid import pack_soa
+    from repro.core.device import thermal_theta0
+    from repro.core.montecarlo import thermal_sigma
+
+    states, seed_rows, sigma_rows, budget_rows, spans = [], [], [], [], []
+    offset = 0
+    for ti, temp in enumerate(grid.temperatures):
+        p_t = (p if temp == p.temperature
+               else dataclasses.replace(p, temperature=float(temp)))
+        key = jax.random.fold_in(jax.random.PRNGKey(grid.seed), ti)
+        k_th, k_ph = jax.random.split(key)
+        zs = jnp.abs(jax.random.normal(k_th, (grid.cells,)))
+        ph = jax.random.uniform(k_ph, (grid.cells,), maxval=2 * jnp.pi)
+        th = zs * thermal_theta0(p_t) + 0.01
+        m0 = jax.vmap(lambda t, f: llg.initial_state(p_t, t, f))(th, ph)
+        v = jnp.repeat(jnp.asarray(grid.voltages, jnp.float32),
+                       grid.n_samples)
+        st = pack_soa(m0, v)
+        padded = st.shape[1]
+        lane = jnp.arange(padded)
+        base = (grid.seed * 0x9E3779B1 + ti * 0x85EBCA6B) & 0xFFFFFFFF
+        states.append(st)
+        seed_rows.append(noise.cell_seeds(base, padded))
+        sigma_rows.append(jnp.full((padded,), thermal_sigma(p_t, grid.dt),
+                                   jnp.float32))
+        budget_rows.append(jnp.where(lane < grid.cells, float(grid.n_steps),
+                                     0.0).astype(jnp.float32))
+        spans.append((offset, offset + grid.cells))
+        offset += padded
+    return (jnp.concatenate(states, axis=1), jnp.concatenate(seed_rows),
+            jnp.concatenate(sigma_rows), jnp.concatenate(budget_rows), spans)
+
+
+def _cell_grid(seed, n_samples=700):
+    """The WER cell's axes (bench/traffic/wer_campaign.json) at a CPU size;
+    700 samples leave bucket padding in every slice."""
+    return CampaignGrid(voltages=(0.6, 1.2), pulse_widths=(120e-12, 250e-12),
+                        temperatures=(300.0, 350.0, 400.0),
+                        n_samples=n_samples, dt=0.1e-12, seed=seed)
+
+
+@pytest.mark.parametrize("p", [AFMTJ_PARAMS, MTJ_PARAMS],
+                         ids=["afmtj", "mtj"])
+@pytest.mark.parametrize("seed", [0, 3100000063])
+def test_pack_program_matches_eager_pack(seed, p):
+    """One compiled pack program gives the eager pack's bits: seeds, sigma
+    and budget rows, and the state block too (the tilt's multiply-add is
+    kept from contracting into an FMA), with the same spans."""
+    grid = _cell_grid(seed)
+    got = pack_campaign(grid, p)
+    want = _eager_pack_campaign(grid, p)
+    for g, w in zip(got[:4], want[:4]):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert got[4] == want[4]
+
+
+def test_pack_program_traces_once_per_shape():
+    """A new seed reuses the traced and compiled pack; a new sample count
+    is a new shape, traced once."""
+    pack_campaign(_cell_grid(1, n_samples=300), AFMTJ_PARAMS)
+    before = telemetry.snapshot()
+    pack_campaign(_cell_grid(2, n_samples=300), AFMTJ_PARAMS)
+    mid = telemetry.snapshot()
+    pack_campaign(_cell_grid(2, n_samples=301), AFMTJ_PARAMS)
+    after = telemetry.snapshot()
+    for name in ("campaign.pack_traces", "xla.compiles"):
+        assert mid.get(name, 0) == before.get(name, 0), name
+    assert (after["campaign.pack_traces"]
+            == mid.get("campaign.pack_traces", 0) + 1)
 
 
 # ----------------------------------------------- fused-T bit-compatibility

@@ -329,6 +329,57 @@ def test_uneven_device_counts_pad_not_demote(n_dev, tmp_path):
     np.testing.assert_array_equal(np.load(out), ref.crossing_steps)
 
 
+def test_four_device_pack_lands_sharded_and_bit_identical(tmp_path):
+    """A single-launch campaign whose lanes divide over 4 devices packs its
+    block straight onto them — no collective in the pack program — and
+    both the block and the streamed WER counts and histogram equal the
+    1-device ones bit for bit."""
+    child = textwrap.dedent("""
+        import re
+        import numpy as np
+        import jax
+        from repro.campaign import CampaignGrid, engine, run_campaign
+        from repro.campaign.engine import _device_plan
+        from repro.campaign.grid import _pack_program, pack_campaign
+        from repro.core.params import AFMTJ_PARAMS
+
+        assert jax.device_count() == 4, jax.devices()
+        grid = CampaignGrid(voltages=(0.6, 1.2),
+                            pulse_widths=(120e-12, 250e-12),
+                            temperatures=(300.0, 350.0, 400.0),
+                            n_samples=600, dt=0.1e-12, seed=3100000063)
+        assert _device_plan(3 * 2048, 4) == (4, 3 * 2048)   # no pad lanes
+        one = pack_campaign(grid, AFMTJ_PARAMS)
+        four = pack_campaign(grid, AFMTJ_PARAMS, n_dev=4)
+        for a, b in zip(one[:4], four[:4]):
+            assert len(b.sharding.device_set) == 4, b.sharding
+            assert b.sharding.spec[-1] == "cells", b.sharding
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        hlo = _pack_program.lower(
+            np.uint32(0), np.arange(3, dtype=np.int32),
+            np.zeros(3, np.uint32), np.ones(3, np.float32),
+            np.ones(3, np.float32), np.ones(2, np.float32), np.float32(9),
+            p=AFMTJ_PARAMS, n_s=600, n_dev=4).compile().as_text()
+        assert not re.search(
+            r"all-gather|all-to-all|all-reduce|collective-permute", hlo)
+        seen = []                     # the layout the engine asks for
+        real = engine.pack_campaign
+        engine.pack_campaign = lambda g, p, n_dev=1: (
+            seen.append(n_dev) or real(g, p, n_dev=n_dev))
+        kw = dict(use_cache=False, reduce="stream", n_bins=32)
+        r1 = run_campaign(AFMTJ_PARAMS, grid, devices=1, **kw)
+        r4 = run_campaign(AFMTJ_PARAMS, grid, devices=4, **kw)
+        assert seen == [1, 4], seen
+        np.testing.assert_array_equal(r4.wer_counts, r1.wer_counts)
+        np.testing.assert_array_equal(r4.latency_hist, r1.latency_hist)
+        # lanes switch and lanes fail, so the counts compare something
+        assert r1.latency_hist.sum() > 0 and r1.wer_counts.max() > 0
+    """)
+    r = subprocess.run([sys.executable, "-c", child], env=_forced_env(4),
+                       capture_output=True, text=True, timeout=560)
+    assert r.returncode == 0, r.stderr
+
+
 # ------------------------------------------------------ lockless claims
 def test_claim_protocol(tmp_path):
     from repro.campaign import cache
