@@ -7,11 +7,29 @@ from typing import Optional, Sequence, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int              # routed experts the router scores
     top_k: int
     d_expert: int                 # per-expert FFN width
     interleave: int = 1           # MoE every Nth layer (1 = every layer)
     shared_expert: bool = False   # llama4-style always-on shared expert
+    d_shared: int = 0             # shared-expert width (0 = d_expert)
+    # router scoring: "softmax" (top-k of the softmax, renormalised) or
+    # "sigmoid" (DeepSeek-V3 noaux_tc: top-k of sigmoid + a selection
+    # bias; the chosen sigmoid scores renormalised), then x routed_scale
+    score: str = "softmax"
+    routed_scale: float = 1.0
+    # expert parallelism: (first, count) of the contiguous range of routed
+    # experts this chip holds; None = all.  The router still scores every
+    # expert; the absent experts' part of the layer is left out.
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def shared_width(self) -> int:
+        return self.d_shared or self.d_expert
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.num_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,6 +53,21 @@ class AttnConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V3, arXiv:2412.19437) without a
+    query low-rank path: K and V come up from one normalised latent per
+    position, and a rotary key part is shared by every head."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str                   # dense | moe | ssm | hybrid | encdec | vlm | audio
@@ -48,10 +81,14 @@ class ArchConfig:
     attn: AttnConfig = AttnConfig()
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    mla: Optional[MLAConfig] = None
     # layer-pattern description: list of (mixer, ffn) strings, repeated to
-    # reach n_layers.  mixer in {attn, attn_local, attn_global, mamba};
+    # reach n_layers.  mixer in {attn, attn_local, attn_global, mamba, mla};
     # ffn in {dense, moe, geglu_dense}.
     pattern: Tuple[Tuple[str, str], ...] = (("attn", "dense"),)
+    # leading layers with a dense FFN of width d_ff before the pattern
+    # starts (DeepSeek-V3's first_k_dense_replace); counted in n_layers
+    first_k_dense: int = 0
     # encoder-decoder
     n_encoder_layers: int = 0
     # frontends (vlm/audio stubs): number of precomputed embedding positions
@@ -60,6 +97,7 @@ class ArchConfig:
     final_softcap: Optional[float] = None    # gemma2: 30.0
     act: str = "silu"                        # silu | gelu
     post_norms: bool = False                 # gemma2 pre+post block norms
+    scale_embed: bool = True                 # embeddings x sqrt(d_model)
     norm_eps: float = 1e-6
     # dtypes: big-MoE models run bf16 optimizer state (see DESIGN.md §4)
     param_dtype: str = "float32"
@@ -68,11 +106,18 @@ class ArchConfig:
 
     @property
     def n_pattern_repeats(self) -> int:
-        assert self.n_layers % len(self.pattern) == 0, (
-            f"{self.name}: n_layers {self.n_layers} not divisible by "
+        n = self.n_layers - self.first_k_dense
+        assert n % len(self.pattern) == 0, (
+            f"{self.name}: {n} pattern layers not divisible by "
             f"pattern length {len(self.pattern)}"
         )
-        return self.n_layers // len(self.pattern)
+        return n // len(self.pattern)
+
+    @property
+    def lead_pattern(self) -> Tuple[Tuple[str, str], ...]:
+        """The pattern of the leading dense layers (repeated
+        ``first_k_dense`` times): the pattern's first mixer, a dense FFN."""
+        return ((self.pattern[0][0], "dense"),)
 
     def param_count(self) -> int:
         """Approximate total parameter count (for 6ND roofline math)."""
@@ -81,6 +126,13 @@ class ArchConfig:
         per_attn = c.d_model * c.d_head * (c.n_heads + 2 * c.n_kv_heads) + (
             c.n_heads * c.d_head * c.d_model
         )
+        if c.mla is not None:
+            m = c.mla
+            per_attn = (c.d_model * c.n_heads * m.qk_head_dim            # wq
+                        + c.d_model * (m.kv_lora_rank + m.qk_rope_head_dim)
+                        + m.kv_lora_rank * c.n_heads
+                        * (m.qk_nope_head_dim + m.v_head_dim)         # wkv_b
+                        + c.n_heads * m.v_head_dim * c.d_model)       # wo
         per_dense_ffn = 3 * c.d_model * c.d_ff
         per_mamba = 0
         if c.ssm is not None:
@@ -91,9 +143,12 @@ class ArchConfig:
                 + d_in * c.ssm.d_conv                       # conv
             )
         total = emb
-        reps = self.n_pattern_repeats
-        for mixer, ffn in c.pattern:
-            if mixer.startswith("attn"):
+        layers = [(mixer, ffn, self.n_pattern_repeats)
+                  for mixer, ffn in c.pattern]
+        layers += [(mixer, ffn, c.first_k_dense)
+                   for mixer, ffn in c.lead_pattern]
+        for mixer, ffn, reps in layers:
+            if mixer.startswith("attn") or mixer == "mla":
                 total += reps * per_attn
             elif mixer == "mamba":
                 total += reps * per_mamba
@@ -103,7 +158,7 @@ class ArchConfig:
                 assert c.moe is not None
                 e = c.moe.num_experts * 3 * c.d_model * c.moe.d_expert
                 if c.moe.shared_expert:
-                    e += 3 * c.d_model * c.moe.d_expert
+                    e += 3 * c.d_model * c.moe.shared_width
                 e += c.d_model * c.moe.num_experts  # router
                 total += reps * e
         if c.n_encoder_layers:

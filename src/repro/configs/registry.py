@@ -4,13 +4,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict
 
-from repro.configs.base import ArchConfig, MoEConfig, SSMConfig
+from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, SSMConfig
 from repro.configs import (  # noqa: F401
     gemma2_2b,
     internlm2_20b,
     jamba_1_5_large_398b,
     llama4_maverick_400b_a17b,
     mamba2_780m,
+    moonlight_16b_a3b,
     olmoe_1b_7b,
     qwen2_0_5b,
     qwen2_vl_2b,
@@ -28,6 +29,7 @@ ARCHS: Dict[str, ArchConfig] = {
         qwen2_vl_2b,
         llama4_maverick_400b_a17b,
         olmoe_1b_7b,
+        moonlight_16b_a3b,
         seamless_m4t_large_v2,
         mamba2_780m,
         jamba_1_5_large_398b,
@@ -45,6 +47,7 @@ TRAIN_MICROBATCHES: Dict[str, int] = {
     "qwen2-vl-2b": 2,
     "llama4-maverick-400b-a17b": 8,
     "olmoe-1b-7b": 2,
+    "moonlight-16b-a3b": 2,
     "seamless-m4t-large-v2": 2,
     "mamba2-780m": 2,
     "jamba-1.5-large-398b": 16,
@@ -63,7 +66,9 @@ def smoke_config(name: str) -> ArchConfig:
     c = get_arch(name)
     kw = dict(
         name=c.name + "-smoke",
-        n_layers=len(c.pattern) * (2 if len(c.pattern) <= 4 else 1),
+        n_layers=(min(c.first_k_dense, 1)
+                  + len(c.pattern) * (2 if len(c.pattern) <= 4 else 1)),
+        first_k_dense=min(c.first_k_dense, 1),
         d_model=64,
         n_heads=4 if c.n_heads else 0,
         n_kv_heads=min(c.n_kv_heads, 2) if c.n_kv_heads else 0,
@@ -83,7 +88,14 @@ def smoke_config(name: str) -> ArchConfig:
             d_expert=64,
             interleave=c.moe.interleave,
             shared_expert=c.moe.shared_expert,
+            d_shared=128 if c.moe.d_shared else 0,
+            score=c.moe.score,
+            routed_scale=c.moe.routed_scale,
         )
+    if c.mla is not None:
+        kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                              qk_rope_head_dim=8, v_head_dim=16)
+        kw["d_head"] = 24
     if c.ssm is not None:
         kw["ssm"] = SSMConfig(d_state=16, headdim=16, expand=2, d_conv=4, chunk=8)
     if c.attn.mrope_sections is not None:

@@ -61,11 +61,12 @@ from repro.imc.faults import FaultSpec, RepairPolicy
 from repro.kernels.fake_analog import (ROW_ATT_NEG, ROW_ATT_POS, ROW_DECODE,
                                        ROW_G_AP, ROW_G_FS, ROW_G_SCALE,
                                        ROW_I_MAX, ROW_R_ACCESS, AUX_ROWS,
+                                       fake_analog_grouped_pallas,
                                        fake_analog_mac_pallas,
                                        pos_neg_conductance)
 from repro.kernels.ops import _default_interpret
 from repro.models import model as model_mod
-from repro.models.common import intercept_linears, rms_norm
+from repro.models.common import group_ids, intercept_linears, rms_norm
 from repro.runtime import telemetry
 
 # bumped when the programming chain changes numerically — stale cache
@@ -86,17 +87,13 @@ def _round_2sig(v: jnp.ndarray) -> jnp.ndarray:
     return jnp.round(v / p) * p
 
 
-def _fake_operands(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
-                   apply_fet: bool, use_fail: bool, ir_drop: bool,
-                   has_imax: bool, decode: bool, use_faults: bool = False,
-                   repair: Optional[RepairPolicy] = None):
-    """Traced operand preamble of the fake-analog ``x @ w``: the
-    ``(v, wn, fail, aux)`` the fused kernel consumes.
-
-    Everything numeric mirrors ``program_weights`` / ``kernel_operands`` /
-    ``analog_matmul`` step for step, with host floats replaced by traced
-    scalars (``scal``) so the whole chain jits."""
-    x = jnp.asarray(x, jnp.float32)
+def _fake_plane(w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
+                apply_fet: bool, use_fail: bool, ir_drop: bool,
+                has_imax: bool, use_faults: bool = False,
+                repair: Optional[RepairPolicy] = None):
+    """Weight side of the operand preamble, one crossbar:
+    ``(wn, fail, att_p, att_n, att_mean, w_scale, g_rms)`` (``g_rms`` None
+    when the ADC full scale is given)."""
     w = jnp.asarray(w, jnp.float32)
     k_rows, n_cols = w.shape
     g_ap, g_fs = scal["g_ap"], scal["g_fs"]
@@ -154,6 +151,39 @@ def _fake_operands(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
         att_n = jnp.ones((n_cols,), jnp.float32) * ok
         att_mean = jnp.float32(1.0)
 
+    g_rms = None
+    if not has_imax:
+        g_diff = att_p[None, :] * tp - att_n[None, :] * tn
+        g_rms = jnp.sqrt(jnp.mean(g_diff * g_diff))
+    return wn, fail, att_p, att_n, att_mean, w_scale, g_rms
+
+
+def _fake_aux(att_p, att_n, i_max, dec, scal: Dict[str, jnp.ndarray]):
+    """The (8, N) aux plane of one crossbar (``ROW_*`` layout)."""
+    full = functools.partial(jnp.full, att_p.shape, dtype=jnp.float32)
+    rows = [None] * AUX_ROWS
+    rows[ROW_ATT_POS], rows[ROW_ATT_NEG] = att_p, att_n
+    rows[ROW_I_MAX], rows[ROW_DECODE] = full(i_max), full(dec)
+    rows[ROW_G_AP], rows[ROW_G_FS] = full(scal["g_ap"]), full(scal["g_fs"])
+    rows[ROW_G_SCALE], rows[ROW_R_ACCESS] = (full(scal["g_scale"]),
+                                             full(scal["r_access"]))
+    return jnp.stack(rows)
+
+
+def _fake_operands(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
+                   apply_fet: bool, use_fail: bool, ir_drop: bool,
+                   has_imax: bool, decode: bool, use_faults: bool = False,
+                   repair: Optional[RepairPolicy] = None):
+    """Traced operand preamble of the fake-analog ``x @ w``: the
+    ``(v, wn, fail, aux)`` the fused kernel consumes.
+
+    Everything numeric mirrors ``program_weights`` / ``kernel_operands`` /
+    ``analog_matmul`` step for step, with host floats replaced by traced
+    scalars (``scal``) so the whole chain jits."""
+    wn, fail, att_p, att_n, att_mean, w_scale, g_rms = _fake_plane(
+        w, bl, scal, apply_fet=apply_fet, use_fail=use_fail, ir_drop=ir_drop,
+        has_imax=has_imax, use_faults=use_faults, repair=repair)
+    x = jnp.asarray(x, jnp.float32)
     x_scale = jnp.max(jnp.abs(x))
     x_scale = jnp.where(x_scale == 0.0, 1.0, x_scale)
     v = scal["v_read"] * x / x_scale
@@ -161,22 +191,53 @@ def _fake_operands(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
     if has_imax:
         i_max = scal["i_max"]
     else:
-        g_diff = att_p[None, :] * tp - att_n[None, :] * tn
-        g_rms = jnp.sqrt(jnp.mean(g_diff * g_diff))
         v_rms = jnp.sqrt(jnp.mean(v * v))
+        i_sigma = v_rms * g_rms * math.sqrt(wn.shape[0])
+        i_max = _round_2sig(jnp.maximum(scal["fs_sigmas"] * i_sigma, 1e-30))
+    dec = ((x_scale * w_scale) / (scal["v_read"] * scal["g_fs"] * att_mean)
+           if decode else jnp.float32(1.0))
+    return v, wn, fail, _fake_aux(att_p, att_n, i_max, dec, scal)
+
+
+def _fake_grouped_operands(x, w, group_sizes, bl: BitlineParams,
+                           scal: Dict[str, jnp.ndarray], *, apply_fet: bool,
+                           use_fail: bool, ir_drop: bool, has_imax: bool,
+                           decode: bool, use_faults: bool = False,
+                           repair: Optional[RepairPolicy] = None):
+    """``_fake_operands`` of a stack of crossbars (one per expert) over rows
+    sorted by expert: ``(v, wn, fail, aux)`` with ``wn``/``fail`` (G, K, N)
+    and ``aux`` (G, 8, N).  The weight side is ``_fake_plane`` mapped over
+    the stack; each expert's input scale max|x| (and the rms behind its ADC
+    full scale) is taken over that expert's own rows."""
+    n_groups, k_rows, _ = w.shape
+    wn, fail, att_p, att_n, att_mean, w_scale, g_rms = jax.vmap(
+        functools.partial(_fake_plane, bl=bl, scal=scal, apply_fet=apply_fet,
+                          use_fail=use_fail, ir_drop=ir_drop,
+                          has_imax=has_imax, use_faults=use_faults,
+                          repair=repair))(w)
+    x = jnp.asarray(x, jnp.float32)
+    gid = group_ids(group_sizes, x.shape[0])        # n_groups past the total
+    seg = functools.partial(jax.ops.segment_max, segment_ids=gid,
+                            num_segments=n_groups + 1)
+    x_scale = seg(jnp.max(jnp.abs(x), axis=1))[:n_groups]
+    x_scale = jnp.where(x_scale > 0.0, x_scale, 1.0)   # 0 or no rows
+    row_scale = jnp.append(x_scale, 1.0)[gid]
+    v = scal["v_read"] * x / row_scale[:, None]
+    v = jnp.where((gid < n_groups)[:, None], v, 0.0)
+
+    if has_imax:
+        i_max = jnp.full((n_groups,), scal["i_max"])
+    else:
+        ssq = jax.ops.segment_sum(jnp.sum(v * v, axis=1), gid,
+                                  num_segments=n_groups + 1)[:n_groups]
+        v_rms = jnp.sqrt(ssq / jnp.maximum(group_sizes * k_rows, 1))
         i_sigma = v_rms * g_rms * math.sqrt(k_rows)
         i_max = _round_2sig(jnp.maximum(scal["fs_sigmas"] * i_sigma, 1e-30))
-    dec = ((x_scale * w_scale) / (scal["v_read"] * g_fs * att_mean)
-           if decode else jnp.float32(1.0))
-
-    full = functools.partial(jnp.full, (n_cols,), dtype=jnp.float32)
-    rows = [None] * AUX_ROWS
-    rows[ROW_ATT_POS], rows[ROW_ATT_NEG] = att_p, att_n
-    rows[ROW_I_MAX], rows[ROW_DECODE] = full(i_max), full(dec)
-    rows[ROW_G_AP], rows[ROW_G_FS] = full(g_ap), full(g_fs)
-    rows[ROW_G_SCALE], rows[ROW_R_ACCESS] = (full(scal["g_scale"]),
-                                             full(scal["r_access"]))
-    return v, wn, fail, jnp.stack(rows)
+    dec = ((x_scale * w_scale) / (scal["v_read"] * scal["g_fs"] * att_mean)
+           if decode else jnp.ones((n_groups,), jnp.float32))
+    aux = jax.vmap(_fake_aux, in_axes=(0, 0, 0, 0, None))(
+        att_p, att_n, i_max, dec, scal)
+    return v, wn, fail, aux
 
 
 def _fake_mvm_body(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
@@ -195,6 +256,26 @@ def _fake_mvm_body(x, w, bl: BitlineParams, scal: Dict[str, jnp.ndarray], *,
                                   apply_fet=apply_fet,
                                   use_fail=use_fail or use_faults,
                                   interpret=interpret, name=name)
+
+
+def _fake_grouped_body(x, w, group_sizes, scal: Dict[str, jnp.ndarray], *,
+                       adc_bits: int, apply_fet: bool, use_fail: bool,
+                       ir_drop: bool, has_imax: bool, decode: bool,
+                       interpret: bool, use_faults: bool = False,
+                       repair: Optional[RepairPolicy] = None,
+                       name: str = "fake_analog_grouped"):
+    """Traced fake-analog grouped product (``models.common.grouped_linear``):
+    one crossbar per expert, rows sorted by expert, through the grouped
+    fused kernel named ``name``."""
+    bl = BitlineParams(rows=w.shape[1])
+    v, wn, fail, aux = _fake_grouped_operands(
+        x, w, group_sizes, bl, scal, apply_fet=apply_fet, use_fail=use_fail,
+        ir_drop=ir_drop, has_imax=has_imax, decode=decode,
+        use_faults=use_faults, repair=repair)
+    return fake_analog_grouped_pallas(v, wn, fail, aux, group_sizes,
+                                      adc_bits=adc_bits, apply_fet=apply_fet,
+                                      use_fail=use_fail or use_faults,
+                                      interpret=interpret, name=name)
 
 
 @functools.lru_cache(maxsize=None)
@@ -399,25 +480,54 @@ def program_weights_cached(
 # ---------------------------------------------------------------------------
 # unrolled model forward + interception hooks
 # ---------------------------------------------------------------------------
-def _forward_unrolled(params, cfg: ArchConfig, tokens: jnp.ndarray):
+def _forward_unrolled(params, cfg: ArchConfig, tokens: jnp.ndarray,
+                      routes=None):
     """Full-sequence logits via an eager layer unroll (no lax.scan — the
     device-path hook reduces to host floats, which cannot cross a scan).
-    Decoder-only: same blocks as ``forward_train``, full logits returned.
-    Device ops carry their layer in ``op_name``: ``block{i}/attn``,
-    ``block{i}/ffn`` and ``unembed`` (the output head)."""
+    Decoder-only: same blocks as ``forward_train``, leading dense layers
+    first, MoE layers dropless; full logits returned.  ``routes``, if a
+    list, gets each MoE layer's expert ids and rows per held expert
+    (``ffn.moe_ffn_dropless``).  Device ops carry their layer in
+    ``op_name``: ``block{i}/attn`` (``block{i}/mla``), ``block{i}/ffn``
+    (``block{i}/moe``) and ``unembed`` (the output head)."""
     assert cfg.n_encoder_layers == 0, "analog routing covers decoder-only"
     x = model_mod._embed(params, cfg, tokens)
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    for rep in range(cfg.n_pattern_repeats):
-        lp = jax.tree_util.tree_map(lambda a: a[rep], params["blocks"])
-        for i, (mixer, f) in enumerate(cfg.pattern):
-            with jax.named_scope(f"block{rep * len(cfg.pattern) + i}"):
-                x, _ = model_mod._run_block(lp[f"pos{i}"], x, cfg, mixer, f,
-                                            positions)
+    stacks = [(params["blocks"], cfg.pattern, cfg.n_pattern_repeats)]
+    if cfg.first_k_dense:
+        stacks.insert(0, (params["lead"], cfg.lead_pattern,
+                          cfg.first_k_dense))
+    layer = 0
+    for stack, pattern, reps in stacks:
+        for rep in range(reps):
+            lp = jax.tree_util.tree_map(lambda a: a[rep], stack)
+            for i, (mixer, f) in enumerate(pattern):
+                with jax.named_scope(f"block{layer}"):
+                    x, _ = model_mod._run_block(lp[f"pos{i}"], x, cfg, mixer,
+                                                f, positions, routes=routes,
+                                                dropless=True)
+                layer += 1
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     with jax.named_scope("unembed"):
         return model_mod._logits(params, cfg, x)
+
+
+def _stacked_routes(routes):
+    """The MoE layers' routes as arrays: ids (layers, tokens, top_k) and
+    sizes (layers, held experts)."""
+    return {k: jnp.stack([r[k] for r in routes]) for k in ("ids", "sizes")}
+
+
+def grouped_plan(cfg: ArchConfig, tokens: int) -> Tuple[int, int]:
+    """(grouped kernel launches, rows handed to them) of one analog forward
+    over ``tokens`` positions: three expert products per MoE layer, each
+    over the layer's (token, choice) pair buffer.  Static: the host knows
+    it without reading the device."""
+    if cfg.moe is None:
+        return 0, 0
+    n_moe = cfg.n_pattern_repeats * sum(f == "moe" for _, f in cfg.pattern)
+    return 3 * n_moe, 3 * n_moe * tokens * cfg.moe.top_k
 
 
 def model_forward_logits(params, cfg: ArchConfig, tokens, hook=None):
@@ -439,29 +549,37 @@ def _jitted_ref_forward(cfg: ArchConfig):
 def _jitted_fake_forward(cfg: ArchConfig, adc_bits: int, apply_fet: bool,
                          use_fail: bool, ir_drop: bool, interpret: bool,
                          use_faults: bool = False,
-                         repair: Optional[RepairPolicy] = None):
-    """Whole forward jitted with the fake-analog hook traced in: one XLA
+                         repair: Optional[RepairPolicy] = None,
+                         with_routes: bool = False):
+    """Whole forward jitted with the fake-analog hooks traced in: one XLA
     executable per (arch, adc_bits[, repair policy]) — TMR/corner/BER/seed
-    and the fault rates arrive as data."""
-    body = functools.partial(_fake_mvm_body, adc_bits=adc_bits,
-                             apply_fet=apply_fet, use_fail=use_fail,
-                             ir_drop=ir_drop, has_imax=False, decode=True,
-                             interpret=interpret, use_faults=use_faults,
-                             repair=repair)
+    and the fault rates arrive as data.  ``with_routes``: it returns
+    ``(logits, routes)`` (``_stacked_routes``) for a model with MoE
+    layers."""
+    flags = dict(adc_bits=adc_bits, apply_fet=apply_fet, use_fail=use_fail,
+                 ir_drop=ir_drop, has_imax=False, decode=True,
+                 interpret=interpret, use_faults=use_faults, repair=repair)
+    body = functools.partial(_fake_mvm_body, **flags)
+    grouped_body = functools.partial(_fake_grouped_body, **flags)
 
     @jax.jit
     def run(params, tokens, scal):
         # rows = K of each site, like the device path's per-layer
         # BitlineParams — shapes are static at trace time, so every site
         # bakes its own IR line length into the one executable; each
-        # kernel is named after its site (``fake_analog_unembed``), which
-        # a device trace shows
+        # kernel is named after its site (``fake_analog_unembed``,
+        # ``fake_analog_moe_w_up``), which a device trace shows
         def hook(x2, w, tag):
             return body(x2, w, BitlineParams(rows=w.shape[0]), scal,
                         name=f"fake_analog_{tag}" if tag else "fake_analog")
 
-        with intercept_linears(hook):
-            return _forward_unrolled(params, cfg, tokens)
+        def grouped(x2, w, sizes, tag):
+            return grouped_body(x2, w, sizes, scal, name=f"fake_analog_{tag}")
+
+        routes = [] if with_routes else None
+        with intercept_linears(hook, grouped):
+            logits = _forward_unrolled(params, cfg, tokens, routes)
+        return (logits, _stacked_routes(routes)) if with_routes else logits
 
     return run
 
@@ -486,10 +604,14 @@ def analog_model_logits(
     tie: int = 1,
     cache_dir: Optional[str] = None,
     interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """Full-sequence logits with every linear routed through the analog MVM.
-    The fake path runs inside the host spans ``repro.analog.prepare`` and
-    ``repro.analog.dispatch`` (DESIGN.md §15)."""
+    with_routes: bool = False,
+):
+    """Full-sequence logits with every linear (and every expert product)
+    routed through the analog MVM.  The fake path runs inside the host
+    spans ``repro.analog.prepare`` and ``repro.analog.dispatch`` (DESIGN.md
+    §15) and counts its grouped launches; with ``with_routes`` it returns
+    ``(logits, routes)``: each MoE layer's expert ids (layers, tokens,
+    top_k) and rows per held expert (layers, held), as device arrays."""
     interp = _default_interpret() if interpret is None else interpret
     if mode == "fake":
         with telemetry.span("analog.prepare"):
@@ -497,15 +619,21 @@ def analog_model_logits(
             fn = _jitted_fake_forward(cfg, acfg.adc_bits, apply_fet,
                                       acfg.write_ber > 0.0, acfg.ir_drop,
                                       interp, _fake_faults_mode(acfg),
-                                      acfg.repair)
+                                      acfg.repair,
+                                      with_routes and cfg.moe is not None)
             # device constants are rows-independent (the FET series
             # combination has no wire term), so one scalar pack serves
             # every layer
             scal = _fake_scalars(kind, acfg, BitlineParams(), g_scale, None)
         batch, seq = jnp.shape(tokens)
+        launches, rows = grouped_plan(cfg, batch * seq)
+        if launches:
+            telemetry.count("analog.grouped_launches", launches)
+            telemetry.count("analog.grouped_rows", rows)
         with telemetry.span("analog.dispatch", batch=batch, seq=seq,
                             adc_bits=acfg.adc_bits):
             return fn(params, tokens, scal)
+    assert not with_routes, "routes come with mode='fake'"
     if mode == "bnn":
         return _jitted_bnn_forward(cfg, tie, interp)(params, tokens)
     if mode == "device":

@@ -193,3 +193,119 @@ def fake_analog_mac_pallas(
     if (mp, np_) != (M, N):
         out = out[:M, :N]
     return out
+
+
+def _grouped_kernel(tile_group_ref, v_ref, w_ref, fail_ref, aux_ref, o_ref,
+                    acc_ref, **kw):
+    """The fused kernel's tile step on one row tile of one group: the group's
+    weight, fail and aux blocks were picked by the scalar-prefetched
+    ``tile_group`` in the index maps, so the tile math is ``_fake_kernel``'s
+    own."""
+    del tile_group_ref
+    _fake_kernel(v_ref, w_ref, fail_ref, aux_ref, o_ref, acc_ref, **kw)
+
+
+def _pad_last2(x: jnp.ndarray, m: int, n: int) -> jnp.ndarray:
+    pm, pn = -x.shape[-2] % m, -x.shape[-1] % n
+    if pm or pn:
+        x = jnp.pad(x, ((0, 0), (0, pm), (0, pn)))
+    return x
+
+
+GROUPED_TILE_MAX = 512
+
+
+def grouped_row_tile(rows: int, groups: int) -> int:
+    """Row tile of the grouped kernel over a ``rows``-row buffer shared by
+    ``groups`` crossbars: the power of two at or above a group's mean
+    share, from ``BM`` to ``GROUPED_TILE_MAX``."""
+    mean = max(1, -(-rows // groups))
+    return min(GROUPED_TILE_MAX, max(BM, 1 << (mean - 1).bit_length()))
+
+
+def fake_analog_grouped_pallas(
+    v: jnp.ndarray,               # (M, K) read voltages, rows sorted by group
+    wn: jnp.ndarray,              # (G, K, N) normalized weights per group
+    fail: jnp.ndarray,            # (G, K, N) f32 fail/fault bit codes
+    aux: jnp.ndarray,             # (G, 8, N) aux plane per group
+    group_sizes: jnp.ndarray,     # (G,) rows of each group, in order
+    adc_bits: int = 0,
+    apply_fet: bool = False,
+    use_fail: bool = False,
+    interpret: bool = False,
+    name: str = "fake_analog_grouped",
+) -> jnp.ndarray:
+    """Grouped (ragged) fused fake-analog MVM: rows of group ``g`` read
+    crossbar ``g`` (its own planes and scales).  Rows past the groups' total
+    come back zero.
+
+    Each group's rows are laid out from a row-tile boundary, so every row
+    tile belongs to one group; the tile -> group map is scalar-prefetched
+    and picks the group's weight, fail and aux blocks in the index maps.
+    The grid's row axis is the number of tiles the groups fill (a traced
+    bound), so tiles past the routed total are never run.  A row tile
+    streams its group's whole weight and fail planes, so the tile is
+    ``grouped_row_tile`` rows: about one tile per group at a few hundred
+    rows a group."""
+    M, K = v.shape
+    G, K2, N = wn.shape
+    assert K == K2, (v.shape, wn.shape)
+    assert fail.shape == wn.shape, (fail.shape, wn.shape)
+    assert aux.shape == (G, AUX_ROWS, N), (aux.shape, G, N)
+    assert group_sizes.shape == (G,), (group_sizes.shape, G)
+    assert adc_bits == 0 or adc_bits >= 2, adc_bits
+    from jax.experimental.pallas import tpu as pltpu
+
+    bm = grouped_row_tile(M, G)
+    sizes = group_sizes.astype(jnp.int32)
+    tiles = (sizes + bm - 1) // bm
+    tile_end = jnp.cumsum(tiles)
+    tile_start = tile_end - tiles
+    row_end = jnp.cumsum(sizes)
+    row_start = row_end - sizes
+    max_tiles = -(-M // bm) + G
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(max_tiles), side="right"),
+        G - 1).astype(jnp.int32)
+
+    # gather the rows into the tile-aligned layout (empty slots read 0 V)
+    slot = jnp.arange(max_tiles * bm)
+    g = tile_group[slot // bm]
+    j = slot - tile_start[g] * bm
+    valid = (slot // bm < tile_end[-1]) & (j < sizes[g])
+    v_al = jnp.where(valid[:, None], v[jnp.where(valid, row_start[g] + j, 0)],
+                     0.0)
+    v_al = _pad2(v_al, bm, BK)
+    wn = _pad_last2(wn, BK, BN)
+    fail = _pad_last2(fail, BK, BN)
+    aux = _pad_last2(aux, AUX_ROWS, BN)
+    _, kp, np_ = wn.shape
+    nk = kp // BK
+    kern = functools.partial(_grouped_kernel, nk=nk, adc_bits=adc_bits,
+                             apply_fet=apply_fet, use_fail=use_fail)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(jnp.maximum(tile_end[-1], 1), np_ // BN, nk),
+        in_specs=[
+            pl.BlockSpec((bm, BK), lambda i, j, k, tg: (i, k)),
+            pl.BlockSpec((None, BK, BN), lambda i, j, k, tg: (tg[i], k, j)),
+            pl.BlockSpec((None, BK, BN), lambda i, j, k, tg: (tg[i], k, j)),
+            pl.BlockSpec((None, AUX_ROWS, BN),
+                         lambda i, j, k, tg: (tg[i], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, BN), lambda i, j, k, tg: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, BN), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kern,
+        out_shape=jax.ShapeDtypeStruct((v_al.shape[0], np_), jnp.float32),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name=name,
+    )(tile_group, v_al, wn, fail, aux)
+
+    # back to the sorted row order; rows past the total read zero
+    gid = jnp.searchsorted(row_end, jnp.arange(M), side="right")
+    gc = jnp.minimum(gid, G - 1)
+    back = tile_start[gc] * bm + jnp.arange(M) - row_start[gc]
+    return jnp.where((gid < G)[:, None], out[back, :N], 0.0)

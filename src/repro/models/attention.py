@@ -85,7 +85,8 @@ def _mask_full(S: int, Skv: int, causal: bool, window: Optional[int], offset: in
 
 
 def full_attention(q, k, v, cfg: ArchConfig, causal: bool, window, offset: int = 0):
-    """Materialized-scores path (seq <= CHUNK_THRESHOLD)."""
+    """Materialized-scores path (seq <= CHUNK_THRESHOLD).  Scores scale by
+    the q/k head width; v may be narrower (latent attention)."""
     B, S, h, hd = q.shape
     kvh = k.shape[2]
     g = h // kvh
@@ -96,7 +97,7 @@ def full_attention(q, k, v, cfg: ArchConfig, causal: bool, window, offset: int =
     scores = scores + _mask_full(S, k.shape[1], causal, window, offset)
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     o = jnp.einsum("bkgst,btkd->bskgd", w, v)
-    return o.reshape(B, S, h, hd)
+    return o.reshape(B, S, h, v.shape[-1])
 
 
 def chunked_attention(q, k, v, cfg: ArchConfig, causal: bool, window, offset: int = 0):
@@ -140,14 +141,14 @@ def chunked_attention(q, k, v, cfg: ArchConfig, causal: bool, window, offset: in
 
     m0 = jnp.full((B, kvh, g, S), -1e30, dtype=jnp.float32)
     l0 = jnp.zeros((B, kvh, g, S), dtype=jnp.float32)
-    a0 = jnp.zeros((B, kvh, g, S, hd), dtype=jnp.float32)
+    a0 = jnp.zeros((B, kvh, g, S, v.shape[-1]), dtype=jnp.float32)
     # checkpoint: FlashAttention semantics — recompute chunk scores in the
     # backward instead of saving [*, S, KV_CHUNK] residuals per chunk.
     (m, l, acc), _ = jax.lax.scan(
         jax.checkpoint(body), (m0, l0, a0), (jnp.arange(n_chunks), kc, vc)
     )
     o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
-    return o.transpose(0, 3, 1, 2, 4).reshape(B, S, h, hd)
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, S, h, v.shape[-1])
 
 
 def self_attention(p, x, cfg: ArchConfig, positions, mixer: str):

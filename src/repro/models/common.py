@@ -72,12 +72,14 @@ def logical_axes(specs: Any) -> Any:
 # linear-layer interception (analog IMC routing — DESIGN.md §12)
 # --------------------------------------------------------------------------
 # All crossbar-mappable GEMMs in the model stack funnel through ``linear``
-# so `imc.model_analog` can reroute them through the differential-conductance
-# MVM without forking the forward code.  The hook is a plain module global
-# (not a context-local): model_analog's unrolled forward is eager and
-# single-threaded, and a global keeps the default path free of any overhead
-# beyond one ``is None`` check.
+# (one weight matrix) or ``grouped_linear`` (a stack of expert matrices over
+# rows sorted by expert) so `imc.model_analog` can reroute them through the
+# differential-conductance MVM without forking the forward code.  The hooks
+# are plain module globals (not context-locals): model_analog's unrolled
+# forward is eager and single-threaded, and a global keeps the default path
+# free of any overhead beyond one ``is None`` check.
 _LINEAR_HOOK = None
+_GROUPED_HOOK = None
 
 
 def linear(x: jnp.ndarray, w: jnp.ndarray, tag: str = "") -> jnp.ndarray:
@@ -94,21 +96,53 @@ def linear(x: jnp.ndarray, w: jnp.ndarray, tag: str = "") -> jnp.ndarray:
     return y.reshape(*lead, w.shape[-1])
 
 
-class intercept_linears:
-    """Context manager installing ``hook(x2d, w, tag) -> y2d`` on ``linear``."""
+def group_ids(group_sizes: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """(rows,) group of each row of a block sorted by group; rows past the
+    groups' total get ``len(group_sizes)``."""
+    ends = jnp.cumsum(group_sizes)
+    return jnp.searchsorted(ends, jnp.arange(rows), side="right")
 
-    def __init__(self, hook):
+
+def grouped_linear(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
+                   tag: str = "") -> jnp.ndarray:
+    """Row block ``g`` of ``x`` times ``w[g]``: the rows ``(M, K)`` are sorted
+    by group, group ``g`` holds the next ``group_sizes[g]`` rows, and rows
+    past the total come back zero.  ``w`` is ``(G, K, N)``.
+
+    With a grouped hook installed it receives ``(x, w, group_sizes, tag)``;
+    with only a ``linear`` hook, each group's rows go through it as their
+    own product (the other rows zeroed), so no group is computed outside
+    the hook."""
+    if _GROUPED_HOOK is not None:
+        return _GROUPED_HOOK(x, w, group_sizes, tag)
+    if _LINEAR_HOOK is None:
+        return jax.lax.ragged_dot(x, w, group_sizes)
+    gid = group_ids(group_sizes, x.shape[0])[:, None]
+    y = jnp.zeros((x.shape[0], w.shape[-1]), jnp.float32)
+    for g in range(w.shape[0]):
+        y = y + jnp.where(gid == g, _LINEAR_HOOK(jnp.where(gid == g, x, 0),
+                                                 w[g], tag), 0.0)
+    return y.astype(x.dtype)
+
+
+class intercept_linears:
+    """Context manager installing ``hook(x2d, w, tag) -> y2d`` on ``linear``
+    and, optionally, ``grouped(x, w_stack, group_sizes, tag) -> y`` on
+    ``grouped_linear``."""
+
+    def __init__(self, hook, grouped=None):
         self.hook = hook
+        self.grouped = grouped
 
     def __enter__(self):
-        global _LINEAR_HOOK
-        self._prev = _LINEAR_HOOK
-        _LINEAR_HOOK = self.hook
+        global _LINEAR_HOOK, _GROUPED_HOOK
+        self._prev = (_LINEAR_HOOK, _GROUPED_HOOK)
+        _LINEAR_HOOK, _GROUPED_HOOK = self.hook, self.grouped
         return self
 
     def __exit__(self, *exc):
-        global _LINEAR_HOOK
-        _LINEAR_HOOK = self._prev
+        global _LINEAR_HOOK, _GROUPED_HOOK
+        _LINEAR_HOOK, _GROUPED_HOOK = self._prev
         return False
 
 

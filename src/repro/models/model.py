@@ -16,6 +16,7 @@ Public API (all pure functions of (params, cfg, ...)):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn
 from repro.models import ffn as ffn_mod
+from repro.models import mla as mla_mod
 from repro.models import ssm as ssm_mod
 from repro.models.common import (
     ParamSpec,
@@ -46,6 +48,8 @@ def _block_specs(cfg: ArchConfig, mixer: str, ffn: str, cross: bool) -> Dict[str
     sp: Dict[str, Any] = {"ln1": ParamSpec((cfg.d_model,), ("embed",), "zeros")}
     if mixer.startswith("attn"):
         sp["attn"] = attn.attn_specs(cfg)
+    elif mixer == "mla":
+        sp["attn"] = mla_mod.mla_specs(cfg)
     elif mixer == "mamba":
         sp["mamba"] = ssm_mod.mamba_specs(cfg)
     else:
@@ -85,6 +89,13 @@ def param_specs(cfg: ArchConfig) -> Dict[str, Any]:
     for i, (mixer, f) in enumerate(cfg.pattern):
         blocks[f"pos{i}"] = _stack_specs(_block_specs(cfg, mixer, f, cross), n_rep)
     specs["blocks"] = blocks
+    if cfg.first_k_dense:
+        # leading dense layers: their own stack, run before ``blocks``
+        assert not cross, "leading dense layers are decoder-only"
+        specs["lead"] = {
+            f"pos{i}": _stack_specs(_block_specs(cfg, mixer, f, cross),
+                                    cfg.first_k_dense)
+            for i, (mixer, f) in enumerate(cfg.lead_pattern)}
     if cross:
         enc_cfg = cfg
         enc = _stack_specs(_block_specs(enc_cfg, "attn", "dense", False),
@@ -123,14 +134,21 @@ def _run_block(
     ffn: str,
     positions,
     mem_kv=None,
+    routes=None,
+    dropless: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Full-sequence block (train/prefill).  Returns (x, aux_loss)."""
+    """Full-sequence block (train/prefill).  Returns (x, aux_loss).
+    ``routes``/``dropless`` go to the MoE layer (``ffn.moe_ffn``)."""
     aux = jnp.zeros((), jnp.float32)
     # named scopes put the sub-layer into every device op's ``op_name``
-    with jax.named_scope("attn" if mixer.startswith("attn") else "ssm"):
+    scope = "attn" if mixer.startswith("attn") else (
+        "mla" if mixer == "mla" else "ssm")
+    with jax.named_scope(scope):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         if mixer.startswith("attn"):
             y = attn.self_attention(p["attn"], h, cfg, positions, mixer)
+        elif mixer == "mla":
+            y = mla_mod.mla_attention(p["attn"], h, cfg, positions)
         else:
             y = ssm_mod.mamba_forward(p["mamba"], h, cfg)
         x = x + _maybe_post(p, "post_ln1", y, cfg)
@@ -139,10 +157,10 @@ def _run_block(
         h = rms_norm(x, p["ln_cross"], cfg.norm_eps)
         x = x + attn.cross_attention(p["cross"], h, mem_kv[0], mem_kv[1], cfg)
     if ffn != "none":
-        with jax.named_scope("ffn"):
+        with jax.named_scope("moe" if ffn == "moe" else "ffn"):
             h = rms_norm(x, p["ln2"], cfg.norm_eps)
             if ffn == "moe":
-                y, a = ffn_mod.moe_ffn(p["ffn"], h, cfg)
+                y, a = ffn_mod.moe_ffn(p["ffn"], h, cfg, routes, dropless)
                 aux = aux + a
             else:
                 y = ffn_mod.dense_ffn(p["ffn"], h, cfg)
@@ -152,13 +170,15 @@ def _run_block(
 
 
 def _scan_pattern(params_blocks, x, cfg: ArchConfig, positions, mem_kv=None,
-                  remat: bool = True):
-    """Scan the repeating pattern over its stacked parameters."""
+                  remat: bool = True, pattern=None):
+    """Scan the repeating pattern (default ``cfg.pattern``) over its stacked
+    parameters."""
     aux_total = jnp.zeros((), jnp.float32)
+    pattern = cfg.pattern if pattern is None else pattern
 
     def body(carry, layer_params):
         x, aux = carry
-        for i, (mixer, f) in enumerate(cfg.pattern):
+        for i, (mixer, f) in enumerate(pattern):
             x, a = _run_block(layer_params[f"pos{i}"], x, cfg, mixer, f,
                               positions, mem_kv)
             aux = aux + a
@@ -175,7 +195,9 @@ def _scan_pattern(params_blocks, x, cfg: ArchConfig, positions, mem_kv=None,
 def _embed(params, cfg: ArchConfig, tokens, frontend_embeds=None):
     dt = jnp.dtype(cfg.compute_dtype)
     e = params["embed"]
-    x = jnp.take(e, tokens, axis=0).astype(dt) * jnp.sqrt(float(cfg.d_model))
+    x = jnp.take(e, tokens, axis=0).astype(dt)
+    if cfg.scale_embed:
+        x = x * jnp.sqrt(float(cfg.d_model))
     if frontend_embeds is not None:
         x = jnp.concatenate([frontend_embeds.astype(dt), x], axis=1)
     return constrain(x, "act_btd")
@@ -264,7 +286,12 @@ def forward_train(params, cfg: ArchConfig, batch: Dict[str, jnp.ndarray]):
         (x, aux), _ = jax.lax.scan(jax.checkpoint(body), (x, aux_total),
                                    params["blocks"])
     else:
-        x, aux = _scan_pattern(params["blocks"], x, cfg, positions)
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.first_k_dense:
+            x, aux = _scan_pattern(params["lead"], x, cfg, positions,
+                                   pattern=cfg.lead_pattern)
+        x, a = _scan_pattern(params["blocks"], x, cfg, positions)
+        aux = aux + a
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
 
@@ -301,19 +328,31 @@ def forward_train(params, cfg: ArchConfig, batch: Dict[str, jnp.ndarray]):
 # --------------------------------------------------------------------------
 # serving
 # --------------------------------------------------------------------------
+def _mixer_cache(cfg: ArchConfig, mixer: str, batch: int, max_seq: int, dt):
+    if mixer.startswith("attn"):
+        return attn.init_kv_cache(cfg, batch, max_seq, dt)
+    if mixer == "mla":
+        return mla_mod.init_mla_cache(cfg, batch, max_seq, dt)
+    return ssm_mod.init_mamba_cache(cfg, batch, dt)
+
+
+def _stacked_cache(cfg: ArchConfig, pattern, n: int, batch: int,
+                   max_seq: int, dt):
+    return {f"pos{i}": jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a[None], (n,) + a.shape),
+        _mixer_cache(cfg, mixer, batch, max_seq, dt))
+        for i, (mixer, _) in enumerate(pattern)}
+
+
 def init_cache(cfg: ArchConfig, batch: int, max_seq: int) -> Dict[str, Any]:
     dt = jnp.dtype(cfg.compute_dtype)
     n_rep = cfg.n_pattern_repeats
-    blocks = {}
-    for i, (mixer, f) in enumerate(cfg.pattern):
-        if mixer.startswith("attn"):
-            c = attn.init_kv_cache(cfg, batch, max_seq, dt)
-        else:
-            c = ssm_mod.init_mamba_cache(cfg, batch, dt)
-        blocks[f"pos{i}"] = jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a[None], (n_rep,) + a.shape), c
-        )
-    cache: Dict[str, Any] = {"pos": jnp.zeros((), jnp.int32), "blocks": blocks}
+    cache: Dict[str, Any] = {
+        "pos": jnp.zeros((), jnp.int32),
+        "blocks": _stacked_cache(cfg, cfg.pattern, n_rep, batch, max_seq, dt)}
+    if cfg.first_k_dense:
+        cache["lead"] = _stacked_cache(cfg, cfg.lead_pattern,
+                                       cfg.first_k_dense, batch, max_seq, dt)
     if cfg.n_encoder_layers:
         kv, hd = cfg.n_kv_heads, cfg.d_head
         cache["cross"] = {
@@ -342,19 +381,25 @@ def serve_prefill(params, cfg: ArchConfig, batch: Dict[str, jnp.ndarray],
 
     aux0 = jnp.zeros((), jnp.float32)
 
-    def body(carry, layer_params):
+    def body(carry, layer_params, pattern):
         x, aux = carry
         ys = {}
-        for i, (mixer, f) in enumerate(cfg.pattern):
+        for i, (mixer, f) in enumerate(pattern):
             lp = layer_params[f"pos{i}"]
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            if mixer.startswith("attn"):
-                q, k, v = attn._project_qkv(lp["attn"], h, cfg, positions)
+            if mixer.startswith("attn") or mixer == "mla":
+                if mixer == "mla":
+                    q, k, v = mla_mod.project_qkv(lp["attn"], h, cfg,
+                                                  positions)
+                    merge = mla_mod.merge_heads
+                else:
+                    q, k, v = attn._project_qkv(lp["attn"], h, cfg, positions)
+                    merge = attn._merge_heads
                 fn = (attn.chunked_attention if S > attn.CHUNK_THRESHOLD
                       else attn.full_attention)
                 window = cfg.attn.sliding_window if mixer == "attn_local" else None
                 o = fn(q, k, v, cfg, causal=True, window=window)
-                y = attn._merge_heads(lp["attn"], o, cfg)
+                y = merge(lp["attn"], o, cfg)
                 pad = max_seq - S
                 ck = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
                 cv = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -383,7 +428,13 @@ def serve_prefill(params, cfg: ArchConfig, batch: Dict[str, jnp.ndarray],
                 x = constrain(x, "act_btd")
         return (x, aux), ys
 
-    (x, _), stacked = jax.lax.scan(body, (x, aux0), params["blocks"])
+    if cfg.first_k_dense:
+        (x, _), cache["lead"] = jax.lax.scan(
+            functools.partial(body, pattern=cfg.lead_pattern), (x, aux0),
+            params["lead"])
+    (x, _), stacked = jax.lax.scan(
+        functools.partial(body, pattern=cfg.pattern), (x, aux0),
+        params["blocks"])
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, cfg, x[:, -1:, :])
@@ -452,7 +503,7 @@ def serve_step(params, cfg: ArchConfig, cache: Dict[str, Any],
         (cache["cross"],) if has_cross else ()
     )
 
-    def body(carry, xs_):
+    def body(carry, xs_, pattern, has_cross):
         x, = carry
         if has_cross:
             layer_params, layer_cache, layer_cross = xs_
@@ -460,12 +511,14 @@ def serve_step(params, cfg: ArchConfig, cache: Dict[str, Any],
             layer_params, layer_cache = xs_
             layer_cross = None
         new_cache = {}
-        for i, (mixer, f) in enumerate(cfg.pattern):
+        for i, (mixer, f) in enumerate(pattern):
             lp = layer_params[f"pos{i}"]
             lc = layer_cache[f"pos{i}"]
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             if mixer.startswith("attn"):
                 y, nc = attn.decode_self_attention(lp["attn"], h, lc, pos, cfg, mixer)
+            elif mixer == "mla":
+                y, nc = mla_mod.mla_decode(lp["attn"], h, lc, pos, cfg)
             else:
                 y, nc = ssm_mod.mamba_decode_step(lp["mamba"], h, lc, cfg)
             new_cache[f"pos{i}"] = nc
@@ -483,10 +536,16 @@ def serve_step(params, cfg: ArchConfig, cache: Dict[str, Any],
                 x = x + _maybe_post(lp, "post_ln2", y, cfg)
         return (x,), new_cache
 
-    (x,), new_blocks = jax.lax.scan(body, (x,), xs_in)
+    new_cache = dict(cache)
+    if cfg.first_k_dense:
+        (x,), new_cache["lead"] = jax.lax.scan(
+            functools.partial(body, pattern=cfg.lead_pattern,
+                              has_cross=False),
+            (x,), (params["lead"], cache["lead"]))
+    (x,), new_cache["blocks"] = jax.lax.scan(
+        functools.partial(body, pattern=cfg.pattern, has_cross=has_cross),
+        (x,), xs_in)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, cfg, x)
-    new_cache = dict(cache)
-    new_cache["blocks"] = new_blocks
     new_cache["pos"] = pos + 1
     return logits, new_cache

@@ -59,7 +59,8 @@ def test_arch_smoke_serve_shapes(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen2-0.5b", "gemma2-2b", "mamba2-780m",
-                                  "jamba-1.5-large-398b", "olmoe-1b-7b"])
+                                  "jamba-1.5-large-398b", "olmoe-1b-7b",
+                                  "moonlight-16b-a3b"])
 def test_decode_matches_forward(arch):
     """Strong consistency: prefill(S) + decode(t) logits == prefill(S+1)'s
     last-token logits, position by position."""
@@ -146,6 +147,7 @@ def test_param_counts_match_names():
         "qwen3-8b": (7e9, 10e9),
         "llama4-maverick-400b-a17b": (350e9, 450e9),
         "olmoe-1b-7b": (6e9, 8e9),
+        "moonlight-16b-a3b": (15e9, 17e9),
         "mamba2-780m": (0.6e9, 1.0e9),
         "jamba-1.5-large-398b": (330e9, 460e9),
     }
@@ -158,3 +160,61 @@ def test_active_params_moe():
     a = ARCHS["llama4-maverick-400b-a17b"]
     act = a.active_param_count()
     assert 12e9 <= act <= 25e9, act   # "a17b"
+
+
+def test_moonlight_param_counts():
+    """MLA, the leading dense layer and the 2816-wide shared expert counted:
+    the published ~16 B total / ~3 B active ("16B-A3B")."""
+    c = ARCHS["moonlight-16b-a3b"]
+    assert 15.5e9 <= c.param_count() <= 16.5e9, c.param_count()
+    assert 2.7e9 <= c.active_param_count() <= 3.2e9, c.active_param_count()
+    # the same count, summed from the parameter tree's shapes (norm scales
+    # and the router bias, which the count leaves out, are under 1e-5)
+    n = sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(
+        M.abstract_params(c)))
+    assert abs(n - c.param_count()) / n < 1e-5, (n, c.param_count())
+
+
+def test_sigmoid_router_matches_numpy():
+    """DeepSeek-V3 routing: sigmoid scores, top-k chosen on score + bias
+    (the bias selects, never weights), chosen scores renormalised and
+    scaled by routed_scale."""
+    from repro.models import ffn as F
+
+    cfg = smoke_config("moonlight-16b-a3b")
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(7, cfg.d_model)).astype(np.float32)
+    p = {"router": rng.normal(size=(cfg.d_model, 4)).astype(np.float32) / 8,
+         "router_bias": np.array([0.3, -0.2, 0.0, 0.1], np.float32)}
+    gates, ids, _, _ = F.route(p, jnp.asarray(x), cfg)
+    s = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ p["router"])))
+    want_ids = np.argsort(-(s + p["router_bias"]), axis=-1)[:, :2]
+    chosen = np.take_along_axis(s, want_ids, -1)
+    want = chosen / chosen.sum(-1, keepdims=True) * 2.446
+    np.testing.assert_array_equal(np.asarray(ids), want_ids)
+    np.testing.assert_allclose(np.asarray(gates), want, rtol=1e-5)
+    # the bias moved at least one choice away from the plain top-k of s
+    assert (np.argsort(-s, axis=-1)[:, :2] != want_ids).any()
+
+
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "moonlight-16b-a3b"])
+def test_dropless_moe_equals_gshard_when_nothing_drops(arch):
+    """The sorted dropless dispatch (grouped_linear over rows sorted by
+    expert) gives the GShard layer's output where no token is dropped
+    (groups of at most 64 tokens are fully dropless)."""
+    from repro.models import ffn as F
+    from repro.models.common import init_params as init_specs
+
+    cfg = smoke_config(arch)
+    p = init_specs(F.moe_specs(cfg), cfg, jax.random.PRNGKey(0))
+    if "router_bias" in p:
+        p["router_bias"] = jax.random.normal(jax.random.PRNGKey(3), (4,)) * .1
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
+    y_gs, _ = F.moe_ffn(p, x, cfg)
+    routes = []
+    y_dl, aux = F.moe_ffn(p, x, cfg, routes, dropless=True)
+    np.testing.assert_allclose(np.asarray(y_dl), np.asarray(y_gs),
+                               atol=1e-5, rtol=1e-5)
+    assert float(aux) == 0.0
+    assert routes[0]["ids"].shape == (32, cfg.moe.top_k)
+    assert int(routes[0]["sizes"].sum()) == 32 * cfg.moe.top_k

@@ -173,6 +173,57 @@ def test_analog_forward_ops_carry_their_layer(tiny_model):
         assert any(scope in n for n in names), scope
 
 
+@pytest.fixture(scope="module")
+def moe_share():
+    """moonlight-16b-a3b's smoke config holding experts 1-2 of 4, with one
+    analog forward already dispatched (and the counters it moved)."""
+    import dataclasses
+    from repro.configs.registry import smoke_config
+    from repro.models import model as M
+
+    cfg = smoke_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                            held=(1, 2)))
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab)
+    acfg = AnalogConfig(adc_bits=0)
+    before = telemetry.snapshot()
+    analog_model_logits(params, cfg, tokens, acfg).block_until_ready()
+    after = telemetry.snapshot()
+    moved = {k: after[k] - before.get(k, 0) for k in after}
+    return cfg, params, tokens, moved
+
+
+def test_moe_forward_counts_grouped_launches_and_rows(moe_share):
+    """Three grouped launches per MoE layer, each handed the layer's
+    (token, choice) pair buffer; the host counts them at dispatch."""
+    cfg, _, tokens, moved = moe_share
+    n_moe = cfg.n_layers - cfg.first_k_dense
+    assert moved["analog.grouped_launches"] == 3 * n_moe
+    assert moved["analog.grouped_rows"] == (3 * n_moe * tokens.size
+                                            * cfg.moe.top_k)
+
+
+def test_moe_forward_ops_carry_their_layer(moe_share):
+    """Latent attention and expert layers name their scopes ``mla`` and
+    ``moe``; each fake-analog kernel is named after its site."""
+    cfg, params, tokens, _ = moe_share
+    fn = _jitted_fake_forward(cfg, 0, False, False, True, True)
+    scal = _fake_scalars("afmtj", AnalogConfig(adc_bits=0), BitlineParams(),
+                         1.0, None)
+    text = fn.lower(params, tokens, scal).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    last = cfg.n_layers - 1
+    for scope in ("block0/mla/", "block0/ffn/fake_analog_w_up",
+                  f"block{last}/mla/fake_analog_wkv_b",
+                  f"block{last}/moe/fake_analog_router",
+                  f"block{last}/moe/fake_analog_moe_w_gate",
+                  f"block{last}/moe/fake_analog_moe_w_down",
+                  f"block{last}/moe/fake_analog_shared_w_up",
+                  "unembed/fake_analog_unembed"):
+        assert any(scope in n for n in names), scope
+
+
 @pytest.mark.parametrize("kernel", ["llg_rk4", "fake_analog", "bitline_mac",
                                     "xnor_gemm"])
 def test_every_pallas_kernel_has_its_name(kernel):

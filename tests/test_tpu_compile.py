@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.params import AFMTJ_PARAMS
 from repro.kernels.bitline_mac import bitline_mac_pallas
-from repro.kernels.fake_analog import AUX_ROWS, fake_analog_mac_pallas
+from repro.kernels.fake_analog import (AUX_ROWS, fake_analog_grouped_pallas,
+                                       fake_analog_mac_pallas)
 from repro.kernels.llg_rk4 import VAR_ROWS, llg_rk4_pallas
 from repro.kernels.xnor_gemm import xnor_gemm_pallas
 
@@ -92,6 +93,24 @@ def test_fake_analog_compiles_for_v5e(one_chip, no_persistent_cache):
                           ((K, N), jnp.float32), ((K, N), jnp.float32),
                           ((AUX_ROWS, N), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+def test_fake_analog_grouped_compiles_for_v5e(one_chip, no_persistent_cache):
+    """moonlight-16b-a3b's expert up-projection on one chip's share: 16
+    experts of 2048 x 1408 over the (token, choice) pairs of 8 x 512
+    tokens at top-6, a traced grid bound and a scalar-prefetched map."""
+    g, d, f, rows = 16, 2048, 1408, 8 * 512 * 6
+
+    def fn(v, wn, fail, aux, sizes):
+        return fake_analog_grouped_pallas(v, wn, fail, aux, sizes,
+                                          adc_bits=8, use_fail=True,
+                                          name="fake_analog_moe_w_up")
+
+    text = _compiled_text(fn, one_chip, ((rows, d), jnp.float32),
+                          ((g, d, f), jnp.float32), ((g, d, f), jnp.float32),
+                          ((g, AUX_ROWS, f), jnp.float32),
+                          ((g,), jnp.int32))
+    assert "tpu_custom_call" in text and "fake_analog_moe_w_up" in text
 
 
 def test_bitline_mac_compiles_for_v5e(one_chip, no_persistent_cache):
