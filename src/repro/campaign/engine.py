@@ -28,7 +28,11 @@ Scaling: the cells axis is embarrassingly parallel, so the engine shards
 cell tiles across every visible device with ``shard_map`` — each device
 integrates its own ``cells / n_dev`` lanes (a multiple of the kernel's
 CELL_TILE, padded with budget-0 lanes when the tiles don't divide the
-mesh — ``_device_plan``), no cross-device communication at all.  Launches
+mesh — ``_device_plan``), no cross-device communication at all.  A
+single launch packed straight onto its devices deals its tiles to them
+round-robin (``grid.deal_tiles``), so every device gets its share of
+each (T, V) block's slow or fast lanes; the crossing row is put back in
+packed order before anything reads it.  Launches
 above ``max_cells_per_launch`` split along temperature-slice boundaries
 and are all dispatched asynchronously before the first
 ``block_until_ready`` — the host never serializes device work against
@@ -59,12 +63,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.campaign import cache as _cache
 from repro.campaign.grid import (CampaignGrid, bucket_cells,
                                  log_horizon_bucket, next_pow2, pack_campaign,
-                                 pack_soa, pack_variation)
+                                 pack_soa, pack_variation, undeal_tiles)
 from repro.core.montecarlo import thermal_sigma
 from repro.core.params import DeviceParams
 from repro.kernels import noise, ref
@@ -273,10 +277,14 @@ def _hist_step_values(n_steps: int, n_bins: int) -> np.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_slices", "slice_cells", "n_v", "n_s", "n_steps", "n_bins"))
+    "n_slices", "slice_cells", "n_v", "n_s", "n_steps", "n_bins", "deal"))
 def _reduce_rows(out, kmin, *, n_slices: int, slice_cells: int, n_v: int,
-                 n_s: int, n_steps: int, n_bins: int):
+                 n_s: int, n_steps: int, n_bins: int, deal: int = 1):
     """On-device reduction of one launch's crossing row.
+
+    ``deal > 1`` marks a launch whose lanes were dealt by tile over that
+    many devices (``grid.deal_tiles``): the crossing row alone is put back
+    in packed order before it is read.
 
     Returns ``(wer_counts, hist)``: int32 ``(n_slices, n_v, n_p)`` counts
     of samples NOT switched by each pulse (exact — see module comment)
@@ -284,7 +292,14 @@ def _reduce_rows(out, kmin, *, n_slices: int, slice_cells: int, n_v: int,
     over *switched* samples.  Only these reduced tensors ever reach the
     host; bucket padding, device-plan padding and never-crossed sentinels
     are all excluded on device."""
-    row7 = out[7, : n_slices * slice_cells].reshape(n_slices, slice_cells)
+    row7 = out[7, : n_slices * slice_cells]
+    if deal > 1:
+        # gather the one row, as an undealt launch does, then put it back in
+        # packed order on every device (a dealt launch has no plan pad)
+        mesh = Mesh(np.asarray(jax.devices()[:deal]), ("cells",))
+        row7 = undeal_tiles(jax.lax.with_sharding_constraint(
+            row7, NamedSharding(mesh, P())), deal)
+    row7 = row7.reshape(n_slices, slice_cells)
     ki = jnp.minimum(row7[:, : n_v * n_s], float(n_steps)).astype(jnp.int32)
     ki = ki.reshape(n_slices, n_v, n_s)
     wer = (ki[:, :, None, :] >= kmin[None, None, :, None]).sum(
@@ -778,12 +793,13 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
     launches = _launch_spans(n_slices, slice_cells, max_cells_per_launch)
     # a single launch whose device plan needs no pad lanes takes the packed
     # block whole (a full slice is the array itself): pack it straight onto
-    # the launch's devices
-    pack_dev = 1
+    # the launch's devices, its tiles dealt round-robin over them, and undo
+    # the deal on the crossing row (``_reduce_rows``, ``_fetch``)
+    deal = 1
     if spec is None and len(launches) == 1:
         plan_dev, plan_cols = _device_plan(n_slices * slice_cells, devices)
         if plan_cols == n_slices * slice_cells:
-            pack_dev = plan_dev
+            deal = plan_dev
 
     def _pack_inputs():
         """(Re-)pack the campaign's device inputs — once up front, and
@@ -792,7 +808,7 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
         to the consumed one)."""
         with telemetry.span("campaign.pack"):
             if spec is None:
-                st, sd, sg, bd, sp = pack_campaign(grid, p, n_dev=pack_dev)
+                st, sd, sg, bd, sp = pack_campaign(grid, p, n_dev=deal)
                 lp = None
             else:
                 st, sd, sg, bd, lp, sp = pack_variation(grid, p)
@@ -861,7 +877,8 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
 
     def dispatch(i: int):
         a, b = launches[i]
-        with telemetry.span("campaign.dispatch", launch=i):
+        dealt = {"deal": deal} if deal > 1 else {}
+        with telemetry.span("campaign.dispatch", launch=i, **dealt):
             c0, c1, plan_cols, statics = launch_plan(a, b)
             st, sd, sg, bd, lp = _pad_lanes(
                 state[:, c0:c1], seeds[c0:c1], sigma[c0:c1], budget[c0:c1],
@@ -872,8 +889,11 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
             if streaming:
                 out = _reduce_rows(out, kmin_dev, n_slices=b - a,
                                    slice_cells=slice_cells, n_v=n_v, n_s=n_s,
-                                   n_steps=n_steps, n_bins=int(n_bins))
+                                   n_steps=n_steps, n_bins=int(n_bins),
+                                   deal=deal)
         telemetry.count("campaign.launches")
+        if dealt:
+            telemetry.count("campaign.dealt_launches")
         telemetry.count("campaign.lanes", (b - a) * grid.cells)
         return out
 
@@ -894,8 +914,10 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
                 payload = {"wer": np.asarray(jax.block_until_ready(wer_d)),
                            "hist": np.asarray(jax.block_until_ready(hist_d))}
             else:
-                blk = np.asarray(jax.block_until_ready(out))
-                payload = {"row7": blk[7][: c1 - c0]}   # trim device-plan pad
+                row7 = np.asarray(jax.block_until_ready(out))[7]
+                if deal > 1:
+                    row7 = undeal_tiles(row7, deal)
+                payload = {"row7": row7[: c1 - c0]}   # trim device-plan pad
         host_bytes += nbytes
         telemetry.count("campaign.host_bytes", nbytes)
         return payload

@@ -263,8 +263,8 @@ def _pack_program(seed, t_index, base, delta, sigma, voltages, n_steps, *,
     indices, per-slice stream bases, Delta and sigma, the voltages and the
     step budget are traced, so a new campaign of the same shape runs
     without tracing; ``campaign.pack_traces`` counts the traces.  With
-    ``n_dev > 1`` the block comes out sharded along its lanes as
-    ``_integrate_sharded`` takes it.
+    ``n_dev > 1`` the block comes out dealt by tile (``deal_tiles``) and
+    sharded along its lanes as ``_integrate_sharded`` takes it.
     """
     telemetry.count("campaign.pack_traces")
     key = jax.random.PRNGKey(seed)
@@ -287,13 +287,40 @@ def _pack_program(seed, t_index, base, delta, sigma, voltages, n_steps, *,
            jnp.concatenate(sigma_rows), jnp.concatenate(budget_rows))
     if n_dev > 1:
         # every device builds the whole block (a few hundred microseconds
-        # of device work) and keeps its own lanes: no collective at all
+        # of device work), deals its tiles round-robin and keeps its own
+        # lanes: no collective at all
         mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("cells",))
         out = tuple(jax.lax.with_sharding_constraint(
-            jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P())),
+            deal_tiles(jax.lax.with_sharding_constraint(
+                x, NamedSharding(mesh, P())), n_dev),
             NamedSharding(mesh, P(*(None,) * (x.ndim - 1), "cells")))
             for x in out)
     return out
+
+
+def deal_tiles(x, n_dev: int):
+    """Deal the lane axis (the last) out to ``n_dev`` devices by whole
+    ``CELL_TILE`` tiles, round-robin: tile ``k`` of the packed order lands
+    in device ``k % n_dev``'s contiguous share, at position ``k // n_dev``
+    there.  A campaign's (T, V) blocks then spread evenly over the devices
+    however slow each block is.  Whole tiles, never single lanes: the
+    kernel exits a tile early once all its lanes are done (DESIGN.md §14),
+    and a tile keeps exactly the lanes, hence the cost, it had.  Takes a
+    numpy or a jax array whose lane count is a multiple of
+    ``n_dev * CELL_TILE``; ``undeal_tiles`` is its inverse."""
+    lead, cols = x.shape[:-1], x.shape[-1]
+    assert cols % (n_dev * CELL_TILE) == 0, (cols, n_dev)
+    tiles = x.reshape(lead + (cols // (n_dev * CELL_TILE), n_dev, CELL_TILE))
+    return tiles.swapaxes(-3, -2).reshape(lead + (cols,))
+
+
+def undeal_tiles(x, n_dev: int):
+    """The lane axis of a ``deal_tiles(., n_dev)`` block back in packed
+    order."""
+    lead, cols = x.shape[:-1], x.shape[-1]
+    assert cols % (n_dev * CELL_TILE) == 0, (cols, n_dev)
+    tiles = x.reshape(lead + (n_dev, cols // (n_dev * CELL_TILE), CELL_TILE))
+    return tiles.swapaxes(-3, -2).reshape(lead + (cols,))
 
 
 def _run_pack(grid: CampaignGrid, p: DeviceParams, slices, n_dev: int = 1):
@@ -322,13 +349,14 @@ def pack_campaign(grid: CampaignGrid, p: DeviceParams, n_dev: int = 1):
 
     The host derives each slice's scalars (inside its ``pack_slice`` span),
     then one call of ``_pack_program`` builds the block on the device;
-    ``n_dev > 1`` lays it out over that many devices, lanes sharded.
+    ``n_dev > 1`` lays it out over that many devices, its tiles dealt
+    round-robin (``deal_tiles``) and its lanes sharded.
 
     Returns ``(state, seeds, sigma, budget, spans)``: the ``(8, cells)``
     SoA block, per-lane uint32 streams, per-lane sigma row [T], per-lane
     step-budget row (``grid.n_steps`` on real lanes, 0 on padding), and
     ``spans[ti] = (start, stop)`` — the real-lane slice of temperature
-    ``ti`` in the packed plane.
+    ``ti`` in the packed plane (before any deal: ``undeal_tiles`` first).
     """
     padded = bucket_cells(grid.cells)
     slices, spans = [], []

@@ -331,16 +331,17 @@ def test_uneven_device_counts_pad_not_demote(n_dev, tmp_path):
 
 def test_four_device_pack_lands_sharded_and_bit_identical(tmp_path):
     """A single-launch campaign whose lanes divide over 4 devices packs its
-    block straight onto them — no collective in the pack program — and
-    both the block and the streamed WER counts and histogram equal the
-    1-device ones bit for bit."""
+    block straight onto them, its tiles dealt round-robin — no collective
+    in the pack program — and the block equals the 1-device one dealt by
+    tile, while the streamed WER counts and histogram and the dense
+    crossing times equal the 1-device ones bit for bit."""
     child = textwrap.dedent("""
         import re
         import numpy as np
         import jax
         from repro.campaign import CampaignGrid, engine, run_campaign
         from repro.campaign.engine import _device_plan
-        from repro.campaign.grid import _pack_program, pack_campaign
+        from repro.campaign.grid import _pack_program, deal_tiles, pack_campaign
         from repro.core.params import AFMTJ_PARAMS
 
         assert jax.device_count() == 4, jax.devices()
@@ -354,7 +355,9 @@ def test_four_device_pack_lands_sharded_and_bit_identical(tmp_path):
         for a, b in zip(one[:4], four[:4]):
             assert len(b.sharding.device_set) == 4, b.sharding
             assert b.sharding.spec[-1] == "cells", b.sharding
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            a, b = np.asarray(a), np.asarray(b)
+            np.testing.assert_array_equal(deal_tiles(a, 4), b)
+            assert not np.array_equal(a, b)        # the deal moved lanes
         hlo = _pack_program.lower(
             np.uint32(0), np.arange(3, dtype=np.int32),
             np.zeros(3, np.uint32), np.ones(3, np.float32),
@@ -374,6 +377,11 @@ def test_four_device_pack_lands_sharded_and_bit_identical(tmp_path):
         np.testing.assert_array_equal(r4.latency_hist, r1.latency_hist)
         # lanes switch and lanes fail, so the counts compare something
         assert r1.latency_hist.sum() > 0 and r1.wer_counts.max() > 0
+        d1 = run_campaign(AFMTJ_PARAMS, grid, devices=1, use_cache=False)
+        d4 = run_campaign(AFMTJ_PARAMS, grid, devices=4, use_cache=False)
+        assert seen == [1, 4, 1, 4], seen
+        np.testing.assert_array_equal(d4.crossing_time, d1.crossing_time)
+        np.testing.assert_array_equal(d1.wer_surface(), r1.wer_surface())
     """)
     r = subprocess.run([sys.executable, "-c", child], env=_forced_env(4),
                        capture_output=True, text=True, timeout=560)
