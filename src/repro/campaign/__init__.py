@@ -10,7 +10,7 @@ with on-disk result caching.
 
   grid    — CampaignGrid axes + SoA packing (fused-CT plane, shape buckets)
   engine  — run_campaign / run_ensemble + surface reductions + early exit
-            + streaming on-device reduction / donation / multi-process
+            + streaming on-device reduction / multi-process
             mesh launch partitioning (DESIGN.md §14)
   cache   — content-addressed npz result cache + lockless work claims
 """

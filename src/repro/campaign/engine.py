@@ -43,10 +43,9 @@ content hash.
 Past one host (DESIGN.md §14): ``reduce="stream"`` keeps even the
 reduction on device — each launch returns exact WER counts and a
 first-crossing histogram instead of its dense lane plane, so host
-transfers are O(grid points) regardless of sample count; ``donate=True``
-donates the state block to the launch so retry rounds reuse device
-memory; and a ``launch.mesh.CampaignMesh`` partitions whole launches
-across processes, which rendezvous lockless-ly through the
+transfers are O(grid points) regardless of sample count; and a
+``launch.mesh.CampaignMesh`` partitions whole launches across processes,
+which rendezvous lockless-ly through the
 content-addressed store (claims + slice checkpoints in ``cache.py``) —
 no collectives, so a mesh of hosts needs nothing but a shared cache
 directory.
@@ -164,23 +163,6 @@ _INTEGRATE_STATICS = ("p", "dt", "n_steps", "switch_threshold", "backend",
                       "n_dev", "chunk")
 _integrate_sharded = jax.jit(_integrate_impl,
                              static_argnames=_INTEGRATE_STATICS)
-# Donated variant (DESIGN.md §14): XLA aliases the (8, cells) state input
-# to the same-shaped output, so retry rounds (write-verify schedules, the
-# engine's own error retries) reuse device memory instead of holding both
-# blocks live.  A *separate* jit object, so every compile-count pin on
-# ``_integrate_sharded`` keeps counting only the default path.  NOTE:
-# aliasing constrains XLA's buffer assignment, and the re-scheduled
-# executable may associate f32 arithmetic differently — observed as rare
-# +-1-step crossing differences vs the undonated compile (deterministic
-# run-to-run; tests/test_scale.py pins repeatability and the statistical
-# envelope).  Donation is therefore opt-in and never the default under a
-# bit-exactness pin.
-_integrate_donated = jax.jit(_integrate_impl,
-                             static_argnames=_INTEGRATE_STATICS,
-                             donate_argnums=(0,))
-# by ``donate``: the jit objects ``run_campaign`` compiles each launch
-# through ahead of its dispatch (see ``compile_launch``)
-_INTEGRATE_JITS = {False: _integrate_sharded, True: _integrate_donated}
 
 
 def _device_count(devices: Optional[int]) -> int:
@@ -387,7 +369,6 @@ def run_ensemble(
     lane_params=None,                # optional (3, cells) variation rows
     sigma_lanes=None,                # optional (cells,) per-lane Brown sigma
     horizon: str = "pow2",           # compiled-horizon ladder (chunk > 0)
-    donate: bool = False,            # donate the state block to the launch
 ) -> EnsembleResult:
     """Integrate an arbitrary thermal ensemble through the kernel path.
 
@@ -445,8 +426,7 @@ def run_ensemble(
     n_static = _quantize_steps(n_steps, horizon) if chunk > 0 else n_steps
 
     t0 = time.time()
-    fn = _integrate_donated if donate else _integrate_sharded
-    out = fn(
+    out = _integrate_sharded(
         state, seeds, sigma, budget, lane_params, p=p, dt=dt,
         n_steps=n_static, switch_threshold=float(switch_threshold),
         backend=backend, n_dev=n_dev, chunk=int(chunk))
@@ -626,7 +606,6 @@ def run_campaign(
     on_slice_complete=None,
     reduce: str = "dense",
     n_bins: int = 512,
-    donate: bool = False,
     mesh=None,
 ) -> CampaignResult:
     """Run (or cache-load) a full Monte-Carlo campaign.
@@ -680,15 +659,6 @@ def run_campaign(
       within ``sketch_tolerance`` (exact when ``n_bins >= grid.n_steps``).
       Streaming results cache under their own derived key, so dense and
       reduced entries never shadow each other.
-    * ``donate=True`` routes launches through ``_integrate_donated``: the
-      (8, cells) state block is donated to XLA, halving peak device
-      residency across retry rounds (write-verify schedules).  A retry
-      whose donated input was consumed re-packs the block from the grid's
-      deterministic draws.  Donated runs are deterministic and
-      statistically identical, but the alias-constrained executable may
-      round rare lanes' crossings one step differently than the default
-      compile (see ``_integrate_donated``) — keep the default for
-      bit-exactness pins.
     * ``mesh`` (a ``launch.mesh.CampaignMesh``) scales past one process:
       ``mesh.n_devices`` shards each launch's cells plane, and with
       ``mesh.process_count > 1`` whole launches are partitioned across
@@ -715,7 +685,7 @@ def run_campaign(
             checkpoint=checkpoint, max_retries=max_retries,
             retry_backoff_s=retry_backoff_s,
             on_slice_complete=on_slice_complete, reduce=reduce,
-            n_bins=n_bins, donate=donate, mesh=mesh)
+            n_bins=n_bins, mesh=mesh)
 
 
 def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
@@ -724,7 +694,7 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
                   max_cells_per_launch: Optional[int], horizon: str,
                   checkpoint: Optional[bool], max_retries: int,
                   retry_backoff_s: float, on_slice_complete, reduce: str,
-                  n_bins: int, donate: bool, mesh) -> CampaignResult:
+                  n_bins: int, mesh) -> CampaignResult:
     """``run_campaign`` inside its ``repro.campaign.run`` span."""
     assert backend in ("pallas", "ref"), backend
     assert reduce in ("dense", "stream"), reduce
@@ -802,10 +772,7 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
             deal = plan_dev
 
     def _pack_inputs():
-        """(Re-)pack the campaign's device inputs — once up front, and
-        again when a donated launch consumed the block before a retry
-        (the draws are deterministic, so a rebuilt block is bit-identical
-        to the consumed one)."""
+        """Pack the campaign's device inputs."""
         with telemetry.span("campaign.pack"):
             if spec is None:
                 st, sd, sg, bd, sp = pack_campaign(grid, p, n_dev=deal)
@@ -873,7 +840,7 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
               jax.ShapeDtypeStruct((lane_params.shape[0], cols),
                                    lane_params.dtype))
         with telemetry.span("campaign.compile", launch=i):
-            _INTEGRATE_JITS[donate].lower(*shapes, lp, **statics).compile()
+            _integrate_sharded.lower(*shapes, lp, **statics).compile()
 
     def dispatch(i: int):
         a, b = launches[i]
@@ -884,8 +851,7 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
                 state[:, c0:c1], seeds[c0:c1], sigma[c0:c1], budget[c0:c1],
                 None if lane_params is None else lane_params[:, c0:c1],
                 plan_cols - (c1 - c0), p)
-            fn = _integrate_donated if donate else _integrate_sharded
-            out = fn(st, sd, sg, bd, lp, **statics)
+            out = _integrate_sharded(st, sd, sg, bd, lp, **statics)
             if streaming:
                 out = _reduce_rows(out, kmin_dev, n_slices=b - a,
                                    slice_cells=slice_cells, n_v=n_v, n_s=n_s,
@@ -949,22 +915,13 @@ def _run_campaign(p: DeviceParams, grid: CampaignGrid, *, backend: str,
 
     def _compute(i: int, out=None) -> Dict[str, np.ndarray]:
         """Dispatch (if not already in flight) + sync launch ``i``, with the
-        retry ladder.  Donation can have consumed the packed inputs by the
-        time a retry needs them — detected via ``is_deleted`` and repaired
-        by re-packing (bit-identical by construction)."""
-        nonlocal state, seeds, sigma, budget, lane_params, n_computed
+        retry ladder."""
+        nonlocal n_computed
         compile_launch(i)
         attempt = 0
         while True:
             try:
                 if out is None:
-                    if donate and state.is_deleted():
-                        state, seeds, sigma, budget, lane_params, _ = (
-                            _pack_inputs())
-                        if single_variation:
-                            state, seeds, sigma, budget, lane_params = (
-                                _bucket_pad(state, seeds, sigma, budget,
-                                            lane_params))
                     out = dispatch(i)
                 payload = _fetch(out, i)
                 n_computed += 1

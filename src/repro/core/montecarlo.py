@@ -11,7 +11,7 @@ that covers the thermal tail).
 launch with in-kernel counter-RNG noise instead of a per-sample
 scan-over-steps.  The original pure-jnp path is kept as
 ``write_error_rate_scan`` — it is the statistical baseline the engine is
-benchmarked against (``benchmarks/run.py --only wer``) and a second,
+tested against (``tests/test_campaign.py``) and a second,
 independently-seeded implementation of the same physics.
 """
 from __future__ import annotations

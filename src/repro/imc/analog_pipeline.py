@@ -279,8 +279,8 @@ def kernel_operands(
     arr: ProgrammedArray, x: jnp.ndarray
 ) -> Tuple[jnp.ndarray, float, float]:
     """The exact (v, i_max, x_scale) ``analog_matmul`` feeds the kernel —
-    exposed so parity checks (``benchmarks.run`` mvm) reconstruct the same
-    operands instead of copying the derivation.
+    exposed so parity checks (``tests/test_analog_pipeline.py``) reconstruct
+    the same operands instead of copying the derivation.
 
     Activations map to bipolar word-line read voltages (``v_read`` full
     scale).  The ADC full scale comes from column-current statistics (an

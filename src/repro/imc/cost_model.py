@@ -9,7 +9,7 @@ technology.  Three technologies share the interface:
 * ``afmtj`` / ``mtj`` — per-unit prices derived from the measured IMC
   hierarchy (``imc.hierarchy.build_hierarchy`` -> MM-level
   ``SubarrayTimings``), the same crossbar mapping ``imc.mapping`` uses for
-  the archmap bench: weight GEMVs run in crossbar mode (a whole XBARxXBAR
+  the archmap study: weight GEMVs run in crossbar mode (a whole XBARxXBAR
   tile per ``t_read + ADC_T``), KV-cache appends are row-serial writes
   (``t_write`` per XBAR-wide row across the parallel arrays).  The measured
   ``wer_target`` / ``write_percentile`` / ``read_percentile`` /

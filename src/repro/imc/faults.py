@@ -19,9 +19,10 @@ variation planes (``kernels/noise.py``): a draw depends only on
 (seed, stream, lane), never on the fault *rate* or the repair policy, so
 
   * rates are **data** — the fake-analog path feeds them as traced scalars
-    and a whole fault-rate sweep reuses ONE XLA compile (pinned in the
-    ``fault`` bench), and raising the rate only *adds* defects (monotone
-    coupling: the u <= rate threshold test shares uniforms across rates);
+    and a whole fault-rate sweep reuses ONE XLA compile (pinned in
+    ``tests/test_faults.py``), and raising the rate only *adds* defects
+    (monotone coupling: the u <= rate threshold test shares uniforms
+    across rates);
   * repair policies are CRN-paired — ``apply_repair`` transforms the same
     defect map, so policy A vs policy B comparisons see identical defects.
 

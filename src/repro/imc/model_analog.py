@@ -806,7 +806,7 @@ def model_degradation_curves(
     """Graceful-degradation curves: model accuracy vs fault rate x repair
     policy (DESIGN.md §13).  A ``FaultSpec`` is present at every point —
     including rate 0 — so each policy's whole rate sweep shares ONE XLA
-    executable (rates are data; pinned in the ``fault`` bench), and the
+    executable (rates are data; pinned in ``tests/test_faults.py``), and the
     counter-RNG keeps the defect maps CRN-paired across policies."""
     state = _setup(arch, smoke, batch, seq_len, seed)
     out = []

@@ -59,8 +59,8 @@ power-of-two-quantized compiled horizon — so a ``max_attempts``-round
 schedule compiles O(log cells) times, not once per round, and a
 ``write_surface`` sweep over (temperature x voltage x pulse) reuses those
 same compiles across its whole grid.  ``ArrayWriteResult.rounds`` records
-the rounds actually run; ``benchmarks/run.py --only write`` reports rounds
-vs XLA compiles.
+the rounds actually run; ``tests/test_fused_engine.py`` pins rounds vs XLA
+compiles.
 """
 from __future__ import annotations
 
@@ -119,13 +119,6 @@ class WritePolicy:
     # retry re-pulses the same junction with fresh thermal history.  Use
     # ``write_verify_corners`` to sweep the corners of a multi-corner spec.
     variation: Optional[VariationSpec] = None
-    # Donate each round's state block to its launch (DESIGN.md §14): retry
-    # rounds then alias instead of accumulating per-round blocks, cutting
-    # peak device memory across the schedule.  Deterministic, but the
-    # alias-constrained compile may differ by +-1 step on rare lanes
-    # (see engine._integrate_donated) — off by default so nominal write
-    # ratios and every compile/bit pin keep the undonated jit.
-    donate: bool = False
 
     def resolved_pulse(self, kind: str) -> float:
         if self.pulse is not None:
@@ -260,7 +253,7 @@ def write_verify(kind: str, n_cells: int,
             n_samples=int(remaining.size), dt=dt,
             seed=policy.seed * 1009 + rnd)
         res = run_campaign(p, grid, backend=policy.backend,
-                           use_cache=policy.use_cache, donate=policy.donate)
+                           use_cache=policy.use_cache)
         ct = res.crossing_time[0, 0]                  # (remaining,)
         ok = ct <= pulse
 
@@ -348,7 +341,7 @@ def _write_verify_variation(kind: str, n_cells: int,
             p, m0, jnp.full((m,), v, jnp.float32), dt, n_steps,
             seed=seed_r, backend=policy.backend, chunk=EARLY_EXIT_CHUNK,
             lane_params=kernel_rows[:, remaining],
-            sigma_lanes=rows.sigma[remaining], donate=policy.donate)
+            sigma_lanes=rows.sigma[remaining])
         ct = res.crossing_time                          # (m,) [s]
         ok = ct <= pulse
 

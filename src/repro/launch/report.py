@@ -96,7 +96,7 @@ class ServingReport:
             else math.inf
 
     def row_dict(self) -> Dict[str, float]:
-        """Flat dict for BENCH.json-style emission."""
+        """Flat dict of the report's figures (``launch.serve`` stats)."""
         d = {
             "requests": self.n_requests,
             "sim_time_s": self.sim_time_s,

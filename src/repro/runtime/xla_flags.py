@@ -5,11 +5,11 @@ but does not default well for collective-heavy programs: how eagerly the
 scheduler hides collective latency under compute, and how aggressively
 small collectives are combined into fewer, larger ones.  The MLPerf-style
 recipes in SNIPPETS.md §2 tune exactly those knobs; this module packages
-them as *named profiles* so a campaign driver (or a bench child process)
+them as *named profiles* so a campaign driver (or a test child process)
 opts in with one env merge instead of a hand-maintained flag string.
 
 XLA reads ``XLA_FLAGS`` once, at backend initialization — so profiles are
-applied to the environment of a *future* process (benchmarks spawn
+applied to the environment of a *future* process (tests spawn
 children; fleet launchers export before exec), never mutated into a live
 one.  ``apply_profile`` refuses (warns and returns the env unchanged)
 when JAX is already initialized in-process, because the flags would

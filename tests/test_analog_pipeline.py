@@ -170,6 +170,32 @@ def test_ir_drop_is_column_gain_error():
     assert r.nmse < 0.05 and r.cosine > 0.97, (r.nmse, r.cosine)
 
 
+@pytest.mark.parametrize("path", ["analog", "bnn"])
+def test_mvm_kernel_matches_oracle_on_its_operands(path):
+    """At a non-128-multiple shape (the padding path), each read kernel
+    equals its jnp oracle on the exact operands the pipeline feeds it:
+    the bit-line MAC behind ``analog_matmul`` (6-bit ADC, within one ADC
+    level) and the XNOR popcount behind ``binary_matmul`` (exact)."""
+    from repro.imc.analog_pipeline import kernel_operands
+    from repro.kernels.xnor_gemm import binarize_acc
+
+    m, k, n = 48, 200, 144
+    kw, kx = jax.random.split(jax.random.PRNGKey(0))
+    w = jax.random.normal(kw, (k, n), jnp.float32) / (k ** 0.5)
+    x = jax.random.normal(kx, (m, k), jnp.float32)
+    if path == "analog":
+        arr = program_weights(w, "afmtj", AnalogConfig(adc_bits=6))
+        v, i_max, _ = kernel_operands(arr, x)
+        np.testing.assert_allclose(
+            np.asarray(ops.bitline_mac(v, arr.g_diff, 6, i_max=i_max)),
+            np.asarray(ref.ref_bitline_mac(v, arr.g_diff, 6, i_max=i_max)),
+            rtol=1e-5, atol=i_max / 31 * 1.001)
+    else:
+        xb, wb = binarize_acc(x, 1), binarize_acc(w, 1)
+        np.testing.assert_array_equal(np.asarray(ops.xnor_gemm(xb, wb)),
+                                      np.asarray(ref.ref_xnor_gemm(xb, wb)))
+
+
 def test_bnn_mode_correlates():
     w, x = _wx()
     y = np.asarray(binary_matmul(x, w))

@@ -377,6 +377,8 @@ def test_campaign_launch_retry_bounded(monkeypatch):
             raise RuntimeError("transient device loss")
         return real(*a, **kw)
 
+    # the launches still compile through the real program
+    flaky.lower = real.lower
     monkeypatch.setattr(engine, "_integrate_sharded", flaky)
     res = engine.run_campaign(AFMTJ_PARAMS, grid, backend="ref",
                               use_cache=False, max_retries=1,
@@ -387,6 +389,7 @@ def test_campaign_launch_retry_bounded(monkeypatch):
         calls["n"] += 1
         raise RuntimeError("dead device")
 
+    always_fails.lower = real.lower
     calls["n"] = 0
     monkeypatch.setattr(engine, "_integrate_sharded", always_fails)
     with pytest.raises(RuntimeError, match="dead device"):
@@ -403,16 +406,18 @@ def test_campaign_compile_error_raises_without_retry(monkeypatch):
 
     calls = {"n": 0}
 
-    def counted(*a, **kw):
-        calls["n"] += 1
-        raise AssertionError("a refused launch must never dispatch")
-
     class Refused:
+        def __call__(self, *a, **kw):
+            calls["n"] += 1
+            raise AssertionError("a refused launch must never dispatch")
+
         def lower(self, *a, **kw):
+            return self
+
+        def compile(self):
             raise NotImplementedError("Unsupported cast: uint32 -> float32")
 
-    monkeypatch.setattr(engine, "_integrate_sharded", counted)
-    monkeypatch.setitem(engine._INTEGRATE_JITS, False, Refused())
+    monkeypatch.setattr(engine, "_integrate_sharded", Refused())
     with pytest.raises(NotImplementedError, match="Unsupported cast"):
         engine.run_campaign(AFMTJ_PARAMS, _resume_grid(), backend="ref",
                             use_cache=False, max_retries=3,

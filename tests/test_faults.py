@@ -338,6 +338,60 @@ def test_fault_slo_curve_degrades_monotonically():
     assert spare[-1].slo_attainment >= none[-1].slo_attainment
 
 
+# --- model degradation curves ------------------------------------------------
+
+@pytest.fixture(scope="module")
+def degradation_curves():
+    """``model_degradation_curves`` at its defaults (qwen2-0.5b smoke
+    forward, batch 2 x seq 64, five rates, no repair vs spare rows), with
+    the number of forward executables each policy compiled."""
+    from repro.imc import model_analog as ma
+
+    cfg, *_ = ma._setup("qwen2-0.5b", True, 2, 64, 0)
+    fns = []
+    for pol in (None, REPAIR_SPARE):
+        acfg = AnalogConfig(adc_bits=6, seed=0,
+                            faults=FaultSpec.at_rate(1e-3, seed=0),
+                            repair=pol)
+        apply_fet, _ = ma._systematic_g_scale(acfg)
+        # the positional arguments ``analog_model_logits`` passes
+        fn = ma._jitted_fake_forward(cfg, 6, apply_fet, False, acfg.ir_drop,
+                                     ma._default_interpret(),
+                                     ma._fake_faults_mode(acfg), pol, False)
+        fn._clear_cache()
+        fns.append(fn)
+    reports = ma.model_degradation_curves("qwen2-0.5b")
+    by_pol = {}
+    for r in reports:
+        by_pol.setdefault(r.repair, []).append(r)
+    return reports, by_pol, [fn._cache_size() for fn in fns]
+
+
+@pytest.mark.parametrize("check", ["monotone", "repair_extends_knee",
+                                   "one_compile_per_policy"])
+def test_model_degradation_curves(degradation_curves, check):
+    """Accuracy degrades monotonically with the fault rate under each
+    policy; spare-row repair extends the knee (or lowers the top-rate KL);
+    and each policy's whole rate sweep runs one compiled forward."""
+    from repro.imc.model_analog import degradation_knee
+
+    reports, by_pol, compiles = degradation_curves
+    assert set(by_pol) == {"none", REPAIR_SPARE.name}
+    if check == "monotone":
+        for rs in by_pol.values():
+            for a, b in zip(rs, rs[1:]):
+                assert a.kl <= b.kl + 1e-9, (a, b)
+                assert a.token_match >= b.token_match - 1e-9, (a, b)
+    elif check == "repair_extends_knee":
+        bar = 0.8 * by_pol["none"][0].token_match
+        knees = degradation_knee(reports, min_token_match=bar)
+        top_none, top_spare = by_pol["none"][-1], by_pol[REPAIR_SPARE.name][-1]
+        assert (knees[REPAIR_SPARE.name] > knees["none"]
+                or top_spare.kl < top_none.kl), knees
+    else:
+        assert compiles == [1, 1], compiles
+
+
 # --- degradation-knee reduction ----------------------------------------------
 
 def test_degradation_knee_reduction():

@@ -160,6 +160,40 @@ def test_eight_device_streaming_campaign_matches_one_device(tmp_path):
 
 
 # ------------------------------------------------------------ xla flags
+def test_gpu_scaling_profile_keeps_wer_bit_identical(tmp_path):
+    """A campaign run in a child process under the ``gpu-scaling``
+    profile's XLA_FLAGS (parsed, inert on CPU) gives WER counts and a
+    latency histogram bit-identical to this process's run."""
+    from repro.campaign import CampaignGrid, run_campaign
+    from repro.core.params import AFMTJ_PARAMS
+
+    child = textwrap.dedent("""
+        import sys
+        import numpy as np
+        from repro.campaign import CampaignGrid, run_campaign
+        from repro.core.params import AFMTJ_PARAMS
+
+        grid = CampaignGrid(voltages=(0.6, 1.2),
+                            pulse_widths=(20e-12, 40e-12),
+                            temperatures=(300.0,), n_samples=512,
+                            dt=0.1e-12, seed=0)
+        res = run_campaign(AFMTJ_PARAMS, grid, backend="ref",
+                           use_cache=False, reduce="stream", n_bins=128)
+        np.savez(sys.argv[1], wer=res.wer_counts, hist=res.latency_hist)
+    """)
+    out = tmp_path / "tuned.npz"
+    _run_child(child, str(out), env=xla_flags.apply_profile("gpu-scaling",
+                                                            _ENV))
+    grid = CampaignGrid(voltages=(0.6, 1.2), pulse_widths=(20e-12, 40e-12),
+                        temperatures=(300.0,), n_samples=512, dt=0.1e-12,
+                        seed=0)
+    ref = run_campaign(AFMTJ_PARAMS, grid, backend="ref", use_cache=False,
+                       reduce="stream", n_bins=128)
+    got = np.load(out)
+    np.testing.assert_array_equal(got["wer"], ref.wer_counts)
+    np.testing.assert_array_equal(got["hist"], ref.latency_hist)
+
+
 def test_flags_for_gpu_scaling_profile():
     s = xla_flags.flags_for("gpu-scaling")
     assert "--xla_gpu_enable_latency_hiding_scheduler=true" in s

@@ -148,8 +148,8 @@ def test_retention_log_horizon_ladder_independent(retention_pair):
 @pytest.fixture(scope="module")
 def retention_stats():
     """Two measurable acceleration rungs for the MLE/Arrhenius stack
-    (same shape as the bench smoke config: both rungs flip >= min_flips
-    lanes within the 1.2 ns window at n=96)."""
+    (both rungs flip >= min_flips lanes within the 1.2 ns window at
+    n=96)."""
     from repro.campaign.grid import log_pulses
 
     return retention_campaign(
@@ -210,6 +210,23 @@ def test_mtj_single_sublattice_path_parity():
     horizon = 2.5e-9
     assert (ct[0, 1] < horizon).all()            # 1.0 V writes
     assert (ct[0, 0] >= horizon).all()           # 0.2 V holds
+
+
+@pytest.fixture(scope="module")
+def disturb_surface():
+    return read_disturb_campaign("afmtj", voltages=(0.10, 0.24),
+                                 pulses=(0.2e-9, 2.0e-9),
+                                 temperatures=(300.0, 400.0), n_samples=128,
+                                 use_cache=False)
+
+
+@pytest.mark.parametrize("t_index", [0, 1], ids=["300K", "400K"])
+def test_disturb_onset_grows_with_bias(disturb_surface, t_index):
+    """Over the longest read pulse the higher sub-threshold bias disturbs
+    more cells than the lower one, at each temperature."""
+    p_lo = disturb_surface.p1(v_index=0, p_index=-1, t_index=t_index)
+    p_hi = disturb_surface.p1(v_index=1, p_index=-1, t_index=t_index)
+    assert p_hi > p_lo, (p_lo, p_hi)
 
 
 def test_disturb_campaign_one_launch_one_compile():

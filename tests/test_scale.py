@@ -1,6 +1,6 @@
 """Scaling-path tests (DESIGN.md §14): streaming on-device reduction,
-donated retry buffers, pad-don't-demote device planning, lockless claims,
-multi-process campaign dedupe, and elastic device-count resume.
+pad-don't-demote device planning, lockless claims, multi-process
+campaign dedupe, and elastic device-count resume.
 
 Multi-device cases run in subprocesses with
 ``--xla_force_host_platform_device_count`` (the flag must precede the
@@ -95,7 +95,7 @@ def test_streaming_sketch_within_documented_tolerance(dense_result):
     err = np.nanmax(np.abs(lp_d - lp_s))
     assert err <= tol, (err, tol)
     # WER stays bit-exact at ANY bin count, and at 128 bins the transfer
-    # shrinks by well over the 4x acceptance floor (BENCH.json re-measures)
+    # shrinks by well over the 4x acceptance floor
     np.testing.assert_array_equal(res.wer_surface(),
                                   dense_result.wer_surface())
     assert res.host_bytes * 4 <= dense_result.host_bytes
@@ -190,89 +190,6 @@ def test_percentiles_from_hist_all_unswitched_is_nan():
     hist = np.zeros((2, 3, 8), dtype=np.int64)
     out = _percentiles_from_hist(hist, np.arange(8.0), (50.0,))
     assert np.isnan(out).all() and out.shape == (2, 3, 1)
-
-
-# ------------------------------------------------------------- donation
-def test_donation_deterministic_and_statistically_identical(dense_result):
-    """Donated launches are deterministic run-to-run; the alias-constrained
-    executable may round rare lanes' crossings one step differently than
-    the default compile, so the pin is repeatability + a tight statistical
-    envelope, not bit equality (see engine._integrate_donated)."""
-    grid = _grid()
-    d1 = run_campaign(AFMTJ_PARAMS, grid, use_cache=False, donate=True)
-    d2 = run_campaign(AFMTJ_PARAMS, grid, use_cache=False, donate=True)
-    np.testing.assert_array_equal(d1.crossing_time, d2.crossing_time)
-    steps_don = np.round(d1.crossing_time / grid.dt)
-    steps_ref = np.round(dense_result.crossing_time / grid.dt)
-    diff = np.abs(steps_don - steps_ref)
-    assert diff.max() <= 1.0, diff.max()
-    assert (diff > 0).mean() < 0.02, (diff > 0).mean()
-
-
-def test_donated_jit_consumes_input():
-    """donate_argnums really donates: the state block is deleted after the
-    launch (that's the memory win), and the donated jit is a distinct
-    object so compile-count pins on the default path stay untouched."""
-    import jax.numpy as jnp
-    from repro.campaign.engine import (EARLY_EXIT_CHUNK, _integrate_donated,
-                                       _integrate_sharded, _quantize_steps)
-    from repro.campaign.grid import pack_campaign
-
-    assert _integrate_donated is not _integrate_sharded
-    grid = _grid(temperatures=(300.0,), n_samples=8)
-    state, seeds, sigma, budget, _ = pack_campaign(grid, AFMTJ_PARAMS)
-    state = jnp.array(state)                 # private copy to sacrifice
-    out = _integrate_donated(
-        state, seeds, sigma, budget, None, p=AFMTJ_PARAMS, dt=grid.dt,
-        n_steps=_quantize_steps(grid.n_steps),
-        switch_threshold=float(grid.switch_threshold), backend="ref",
-        n_dev=1, chunk=EARLY_EXIT_CHUNK)
-    out.block_until_ready()
-    assert state.is_deleted()
-
-
-def test_donation_retry_repacks_consumed_inputs(monkeypatch):
-    """A retry after the donated block was consumed must re-pack instead
-    of dereferencing a deleted buffer."""
-    from repro.campaign import engine
-
-    grid = _grid(temperatures=(300.0,), n_samples=8)
-    real = engine._integrate_donated
-    calls = {"n": 0}
-
-    def flaky(*a, **kw):
-        calls["n"] += 1
-        out = real(*a, **kw)
-        if calls["n"] == 1:
-            # the donated input is already consumed; now fail the launch
-            out.block_until_ready()
-            raise RuntimeError("transient loss after donation")
-        return out
-
-    monkeypatch.setattr(engine, "_integrate_donated", flaky)
-    res = engine.run_campaign(AFMTJ_PARAMS, grid, use_cache=False,
-                              donate=True, max_retries=1,
-                              retry_backoff_s=0.0)
-    assert calls["n"] == 2
-    clean = engine.run_campaign(AFMTJ_PARAMS, grid, use_cache=False,
-                                donate=True)
-    np.testing.assert_array_equal(res.crossing_time, clean.crossing_time)
-
-
-def test_write_verify_donate_smoke():
-    """The write-verify scheduler accepts the donation knob end to end and
-    still writes reliably (statistical check only — donation is not under
-    the bit pins)."""
-    import dataclasses as _dc
-
-    from repro.imc.write_path import WritePolicy, write_verify
-    pol = WritePolicy(v_write=1.0, pulse=130e-12, max_attempts=3, seed=5,
-                      use_cache=False, donate=True)
-    res = write_verify("afmtj", 96, pol)
-    ref = write_verify("afmtj", 96, _dc.replace(pol, donate=False))
-    assert abs(res.success.mean() - ref.success.mean()) <= 0.05
-    assert abs(res.attempts_mean - ref.attempts_mean) <= 0.25
-    assert res.rounds == ref.rounds
 
 
 # ------------------------------------------------- device planning (pad)

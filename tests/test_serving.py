@@ -373,6 +373,36 @@ def test_slo_normalized_attainable_below_capacity():
     assert reps[2.0].slo_attainment < 0.5
 
 
+@pytest.fixture(scope="module")
+def tpot_p99():
+    """p99 time per output token of qwen2-0.5b served at offered load 0.8
+    on 8 slots, 20,000 Poisson requests per technology at nominal
+    prices."""
+    from repro.configs.registry import ARCHS
+    from repro.imc.cost_model import device_cost_model
+
+    tc = per_token_counts(ARCHS["qwen2-0.5b"])
+    out = {}
+    for tech in ("afmtj", "mtj", "cpu"):
+        prices = device_cost_model(tech).token_prices(tc)
+        trace = poisson_at_load(prices, 0.8, 20_000, 8, seed=11).trace()
+        slo = SLO.normalized(prices, CHAT_PROMPTS, CHAT_OUTPUTS, 8)
+        r = simulate_serving(prices, trace, n_slots=8)
+        out[tech] = build_report(
+            tech, r.ttft_s, r.tpot_s, r.sim_time_s, r.energy_j,
+            r.prefill_tokens, r.decode_tokens, offered_load=0.8, slo=slo,
+            busy_s=r.busy_s).tpot_p99_s
+    return out
+
+
+@pytest.mark.parametrize("baseline", ["mtj", "cpu"])
+def test_afmtj_beats_baseline_p99_tpot(tpot_p99, baseline):
+    """The serving case study: every generated token pays its KV append
+    on the write path, so the AFMTJ's faster writes show in the p99 tail
+    against the MTJ and the CPU baseline alike."""
+    assert tpot_p99["afmtj"] < tpot_p99[baseline], tpot_p99
+
+
 # --------------------------------------------------------------------------
 # evaluate: geometric-mean summary (satellite)
 # --------------------------------------------------------------------------

@@ -187,6 +187,16 @@ def test_fused_corners_bit_identical_to_single_corner_launches(var_grid,
     assert np.isfinite(ff) and np.isfinite(ss) and ff < ss
 
 
+def test_slow_corner_binds_wer(var_result):
+    """The slow corner is the reliability binder: at every (V, pulse)
+    rung its worst-temperature WER is at least the fast corner's, and
+    above it somewhere."""
+    worst = var_result.wer_surface().max(axis=1)      # (C, V, P)
+    ff, ss = worst[0], worst[2]
+    assert (ss >= ff).all(), (ss, ff)
+    assert (ss > ff).any(), (ss, ff)
+
+
 def test_nominal_corner_statistically_matches_legacy_engine(var_grid):
     """An all-nominal variation campaign rides the per-lane parameter rows
     (different rounding path than the scalar closure -> chaotic divergence
