@@ -95,7 +95,7 @@ class Sizes:
 
 CHIP = Sizes(samples=100_000, slice_lanes=4096, write_cells=65536,
              arch="full", seq=128, proj=(128, 896, 4864))
-REHEARSAL = Sizes(samples=64, slice_lanes=512, write_cells=512,
+REHEARSAL = Sizes(samples=64, slice_lanes=1024, write_cells=512,
                   arch="smoke", seq=16, proj=(8, 64, 128))
 
 

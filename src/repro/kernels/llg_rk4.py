@@ -5,8 +5,12 @@ cell of a subarray (and every Monte-Carlo sample) — restructured for TPU:
 
 * SoA layout ``(8, cells)``: rows 0-2 = m1, rows 3-5 = m2, row 6 = per-cell
   drive voltage, row 7 = first-crossing step (written by the kernel).
-  Lane dimension = cells (multiples of 128), so every vector op in the RK4
-  update is a full-width VPU op.
+  Lane dimension = cells.  The thermal kernel carries every per-lane
+  quantity as a ``(1, CELL_TILE)`` row, which Mosaic tiles ``(1, 128)``:
+  one vreg holds 8 x 128 = 1,024 lanes of such a row.  ``CELL_TILE`` is
+  1,024, so each quantity of the step fills exactly one vreg — a 512-lane
+  row fills half of one for the same vector ops per step, a 2,048-lane
+  row doubles the ops (``tests/test_tpu_compile.py`` counts them).
 * One grid step owns a ``(8, CELL_TILE)`` VMEM-resident tile and advances it
   up to ``n_steps`` — HBM traffic is O(cells), compute O(cells * steps):
   arithmetic intensity ~ 60 flops/step/cell keeps the tile compute-bound
@@ -53,7 +57,7 @@ from jax.experimental import pallas as pl
 from repro.core.params import GAMMA, DeviceParams
 from repro.kernels import noise
 
-CELL_TILE = 512
+CELL_TILE = 1024  # lanes per grid step: one (1,128)-tiled vreg per row
 ROWS = 8
 AUX_ROWS = 2     # aux plane: row 0 = per-lane sigma [T], row 1 = step budget
 # Variation plane (DESIGN.md §9): the aux input grows three per-lane device

@@ -18,6 +18,7 @@ from repro.campaign import (CampaignGrid, brown_sigma, pack_plane,
 from repro.core import llg
 from repro.core.params import AFMTJ_PARAMS
 from repro.kernels import noise, ops, ref
+from repro.kernels.llg_rk4 import CELL_TILE
 
 
 # ------------------------------------------------------------ noise streams
@@ -37,7 +38,7 @@ def test_noise_stream_statistics():
 
 
 def test_noise_stream_deterministic():
-    seeds = noise.cell_seeds(7, 512)
+    seeds = noise.cell_seeds(7, CELL_TILE)
     a, _ = noise.thermal_draws(seeds, jnp.int32(11))
     b, _ = noise.thermal_draws(seeds, jnp.int32(11))
     assert all(np.array_equal(np.asarray(x), np.asarray(y))
@@ -53,12 +54,13 @@ def _states(cells, seed=0):
     return ops.pack_states(m0, jnp.linspace(0.3, 1.2, cells))
 
 
+@pytest.mark.parametrize("cells", [CELL_TILE, 2 * CELL_TILE])
 @pytest.mark.parametrize("n_steps", [50, 200])
-def test_thermal_kernel_matches_ref_exact_stream(n_steps):
+def test_thermal_kernel_matches_ref_exact_stream(n_steps, cells):
     """Pallas-with-noise vs ref.py oracle at a fixed seed: the counter-RNG
     is stateless, so both consume the *identical* thermal stream and the
     trajectories must agree to float tolerance (not just statistically)."""
-    cells, dt = 512, 0.1e-12
+    dt = 0.1e-12
     state = _states(cells)
     sigma = brown_sigma(AFMTJ_PARAMS, dt)
     seeds = noise.cell_seeds(42, cells)
@@ -71,8 +73,8 @@ def test_thermal_kernel_matches_ref_exact_stream(n_steps):
 
 
 def test_thermal_zero_sigma_reduces_to_deterministic():
-    state = _states(512, seed=2)
-    out_t = ops.llg_rk4_thermal(state, noise.cell_seeds(0, 512),
+    state = _states(CELL_TILE, seed=2)
+    out_t = ops.llg_rk4_thermal(state, noise.cell_seeds(0, CELL_TILE),
                                 AFMTJ_PARAMS, 0.1e-12, 100, 0.0)
     out_d = ops.llg_rk4(state, AFMTJ_PARAMS, 0.1e-12, 100)
     # the thermal kernel adds an exact 0.0 field, but XLA fuses the add
@@ -84,10 +86,11 @@ def test_thermal_zero_sigma_reduces_to_deterministic():
 
 def test_thermal_seeds_decorrelate_lanes():
     """Same initial state on every lane + noise => lanes must diverge."""
-    m0 = jnp.broadcast_to(llg.initial_state(AFMTJ_PARAMS, 0.1, 0.3), (512, 2, 3))
-    state = ops.pack_states(m0, jnp.full((512,), 1.0))
+    m0 = jnp.broadcast_to(llg.initial_state(AFMTJ_PARAMS, 0.1, 0.3),
+                          (CELL_TILE, 2, 3))
+    state = ops.pack_states(m0, jnp.full((CELL_TILE,), 1.0))
     sigma = brown_sigma(AFMTJ_PARAMS, 0.1e-12)
-    out = ops.llg_rk4_thermal(state, noise.cell_seeds(1, 512),
+    out = ops.llg_rk4_thermal(state, noise.cell_seeds(1, CELL_TILE),
                               AFMTJ_PARAMS, 0.1e-12, 200, sigma)
     nz = np.asarray(0.5 * (out[2] - out[5]))
     assert nz.std() > 1e-3
@@ -150,7 +153,7 @@ def test_pack_soa_single_sublattice_layout():
     m0 = jax.vmap(lambda t: llg.initial_state(MTJ_PARAMS, t, 0.1))(
         jnp.linspace(0.01, 0.2, 8))
     state = pack_soa(m0, jnp.linspace(0.8, 1.2, 8))
-    assert state.shape[0] == 8 and state.shape[1] % 512 == 0
+    assert state.shape[0] == 8 and state.shape[1] % CELL_TILE == 0
     np.testing.assert_allclose(
         np.linalg.norm(np.asarray(state[0:3, :8]), axis=0), 1.0, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(state[3:6]), 0.0)
@@ -446,7 +449,7 @@ def test_pack_plane_layout():
     grid = CampaignGrid(voltages=(0.5, 1.0), pulse_widths=(100e-12,),
                         n_samples=10, dt=0.1e-12)
     state, seeds = pack_plane(grid, AFMTJ_PARAMS, 0)
-    assert state.shape[0] == 8 and state.shape[1] % 512 == 0
+    assert state.shape[0] == 8 and state.shape[1] % CELL_TILE == 0
     assert seeds.shape == (state.shape[1],) and seeds.dtype == jnp.uint32
     # voltage row: sample s of voltage i at lane i*n_samples + s
     v = np.asarray(state[6, :grid.cells])

@@ -215,14 +215,15 @@ def test_multi_temperature_campaign_compiles_once(fused_grid):
 
 
 def test_write_verify_stays_within_bucket_compile_budget():
-    """A shrinking retry schedule (640 -> ~300 -> ~140 -> ...) touches two
-    shape buckets (1024, 512): compiles must stay below the round count."""
+    """A shrinking retry schedule (1280 -> ~600 -> ~280 -> ...) touches two
+    shape buckets (2 and 1 ``CELL_TILE`` tiles): compiles must stay below
+    the round count."""
     from repro.imc.write_path import WritePolicy, write_verify
 
     _integrate_sharded._clear_cache()
     pol = WritePolicy(v_write=1.0, pulse=130e-12, max_attempts=3, seed=5,
                       use_cache=False)
-    r = write_verify("afmtj", 640, pol)
+    r = write_verify("afmtj", CELL_TILE + CELL_TILE // 4, pol)
     assert r.rounds == 3                      # short pulse: retries happen
     assert _integrate_sharded._cache_size() <= 2 < r.rounds
 
@@ -239,7 +240,7 @@ def _packed_states(cells, seed=0):
 def test_kernel_early_exit_crossings_bit_identical():
     """Chunked early exit must reproduce the fixed-horizon crossing row
     bit-for-bit, and leave never-crossed lanes' magnetization untouched."""
-    cells, dt, n_steps = 512, 0.1e-12, 1600
+    cells, dt, n_steps = CELL_TILE, 0.1e-12, 1600
     state = _packed_states(cells)
     sigma = brown_sigma(AFMTJ_PARAMS, dt)
     seeds = noise.cell_seeds(3, cells)
@@ -256,15 +257,46 @@ def test_kernel_early_exit_crossings_bit_identical():
                                       np.asarray(fixed[:6])[:, still])
 
 
+def test_crossing_step_does_not_depend_on_tile_neighbours():
+    """Lanes share a tile only through its early exit: a lane crosses at
+    the same step beside neighbours that run the whole horizon (slow 0.6 V
+    lanes that never cross) as beside budget-0 pads (the tile exits once
+    the lane is done).  Probes sit at both ends of the tile and in
+    different 128-lane rows of it, driven hard enough to cross early."""
+    cells, dt, n_steps, chunk = CELL_TILE, 0.1e-12, 1600, 64
+    probe = np.array([0, 129, cells // 2 + 7, cells - 1])
+    state = np.array(_packed_states(cells, seed=4))
+    state[6] = 0.6
+    state[6, probe] = 2.0
+    state = jnp.asarray(state)
+    seeds = noise.cell_seeds(9, cells)
+    sigma = brown_sigma(AFMTJ_PARAMS, dt)
+    alone = np.zeros(cells, np.float32)
+    alone[probe] = n_steps
+    crossing = {}
+    for name, budget in (("beside_slow", np.full(cells, n_steps, np.float32)),
+                         ("beside_pads", alone)):
+        out = ops.llg_rk4_thermal(state, seeds, AFMTJ_PARAMS, dt, n_steps,
+                                  sigma, step_budget=jnp.asarray(budget),
+                                  chunk=chunk)
+        crossing[name] = np.asarray(out[7])
+    # the probes cross chunks before the horizon, while most of their
+    # neighbours are still integrating at its end
+    assert crossing["beside_pads"][probe].max() < n_steps - 2 * chunk
+    assert (crossing["beside_slow"] >= n_steps).mean() > 0.5
+    np.testing.assert_array_equal(crossing["beside_slow"][probe],
+                                  crossing["beside_pads"][probe])
+
+
 def test_kernel_per_lane_sigma_matches_ref_two_temperatures():
     """Two temperatures in one launch: the Pallas kernel and the jnp oracle
     consume identical per-lane sigma rows and identical streams."""
-    cells, dt, n_steps = 512, 0.1e-12, 200
+    cells, dt, n_steps = CELL_TILE, 0.1e-12, 200
     state = _packed_states(cells, seed=1)
     seeds = noise.cell_seeds(11, cells)
     sig = np.empty(cells, np.float32)
-    sig[:256] = brown_sigma(AFMTJ_PARAMS, dt, 260.0)
-    sig[256:] = brown_sigma(AFMTJ_PARAMS, dt, 340.0)
+    sig[:cells // 2] = brown_sigma(AFMTJ_PARAMS, dt, 260.0)
+    sig[cells // 2:] = brown_sigma(AFMTJ_PARAMS, dt, 340.0)
     sig = jnp.asarray(sig)
     out_k = ops.llg_rk4_thermal(state, seeds, AFMTJ_PARAMS, dt, n_steps,
                                 sig, chunk=32)
@@ -283,7 +315,7 @@ def test_kernel_step_budget_clips_like_shorter_horizon():
     """Integrating to a quantized horizon with a per-lane budget must equal
     (after sentinel clipping) integrating exactly to the budget — the §8
     recompile-free pulse-horizon contract."""
-    cells, dt = 512, 0.1e-12
+    cells, dt = CELL_TILE, 0.1e-12
     state = _packed_states(cells, seed=2)
     sigma = brown_sigma(AFMTJ_PARAMS, dt)
     seeds = noise.cell_seeds(7, cells)

@@ -14,6 +14,7 @@ except ImportError:   # property tests skip; allclose sweeps still run
 from repro.core import llg
 from repro.core.params import AFMTJ_PARAMS
 from repro.kernels import ops, ref
+from repro.kernels.llg_rk4 import CELL_TILE
 
 
 def _states(cells, seed=0, vmin=0.3, vmax=1.2):
@@ -25,7 +26,7 @@ def _states(cells, seed=0, vmin=0.3, vmax=1.2):
     return ops.pack_states(m0, v)
 
 
-@pytest.mark.parametrize("cells", [512, 1024])
+@pytest.mark.parametrize("cells", [CELL_TILE, 2 * CELL_TILE])
 @pytest.mark.parametrize("n_steps", [50, 400])
 def test_llg_rk4_matches_ref(cells, n_steps):
     state = _states(cells)
@@ -42,7 +43,7 @@ def test_llg_rk4_param_sweep():
     for alpha, bes in [(0.005, 1.0), (0.02, 0.5), (0.01, 2.0)]:
         p = dataclasses.replace(AFMTJ_PARAMS, alpha=alpha,
                                 b_exchange=AFMTJ_PARAMS.b_exchange * bes)
-        state = _states(512, seed=3)
+        state = _states(CELL_TILE, seed=3)
         out_k = ops.llg_rk4(state, p, 0.1e-12, 100)
         out_r = ref.ref_llg_rk4(state, p, 0.1e-12, 100)
         np.testing.assert_allclose(np.asarray(out_k[:6]), np.asarray(out_r[:6]),
@@ -50,7 +51,7 @@ def test_llg_rk4_param_sweep():
 
 
 def test_llg_rk4_norm_invariant():
-    out = ops.llg_rk4(_states(512), AFMTJ_PARAMS, 0.1e-12, 200)
+    out = ops.llg_rk4(_states(CELL_TILE), AFMTJ_PARAMS, 0.1e-12, 200)
     n1 = np.linalg.norm(np.asarray(out[0:3]), axis=0)
     n2 = np.linalg.norm(np.asarray(out[3:6]), axis=0)
     np.testing.assert_allclose(n1, 1.0, atol=1e-5)
@@ -107,6 +108,6 @@ def test_pack_unpack_roundtrip():
         jnp.linspace(0.01, 0.3, 100))
     v = jnp.linspace(0.2, 1.0, 100)
     state = ops.pack_states(m0, v)
-    assert state.shape == (8, 512)          # padded to CELL_TILE
+    assert state.shape == (8, CELL_TILE)    # padded to CELL_TILE
     m, cross = ops.unpack_states(state, 100)
     np.testing.assert_allclose(np.asarray(m), np.asarray(m0), atol=1e-6)

@@ -216,12 +216,13 @@ def test_uneven_device_counts_pad_not_demote(n_dev, tmp_path):
         from repro.campaign.engine import _device_plan, run_ensemble
         from repro.core import llg
         from repro.core.params import AFMTJ_PARAMS
+        from repro.kernels.llg_rk4 import CELL_TILE
 
         n_dev = int(sys.argv[2])
         assert jax.device_count() == n_dev, jax.devices()
         got_n, plan_cols = _device_plan(2048, None)
         assert got_n == n_dev, (got_n, n_dev)       # padded, NOT demoted
-        assert plan_cols % (512 * n_dev) == 0 and plan_cols >= 2048
+        assert plan_cols % (CELL_TILE * n_dev) == 0 and plan_cols >= 2048
 
         m0 = jax.vmap(lambda t: llg.initial_state(AFMTJ_PARAMS, t, 0.2))(
             jnp.linspace(0.05, 0.15, 2048))
@@ -265,8 +266,8 @@ def test_four_device_pack_lands_sharded_and_bit_identical(tmp_path):
         grid = CampaignGrid(voltages=(0.6, 1.2),
                             pulse_widths=(120e-12, 250e-12),
                             temperatures=(300.0, 350.0, 400.0),
-                            n_samples=600, dt=0.1e-12, seed=3100000063)
-        assert _device_plan(3 * 2048, 4) == (4, 3 * 2048)   # no pad lanes
+                            n_samples=1200, dt=0.1e-12, seed=3100000063)
+        assert _device_plan(3 * 4096, 4) == (4, 3 * 4096)   # no pad lanes
         one = pack_campaign(grid, AFMTJ_PARAMS)
         four = pack_campaign(grid, AFMTJ_PARAMS, n_dev=4)
         for a, b in zip(one[:4], four[:4]):
@@ -279,7 +280,7 @@ def test_four_device_pack_lands_sharded_and_bit_identical(tmp_path):
             np.uint32(0), np.arange(3, dtype=np.int32),
             np.zeros(3, np.uint32), np.ones(3, np.float32),
             np.ones(3, np.float32), np.ones(2, np.float32), np.float32(9),
-            p=AFMTJ_PARAMS, n_s=600, n_dev=4).compile().as_text()
+            p=AFMTJ_PARAMS, n_s=1200, n_dev=4).compile().as_text()
         assert not re.search(
             r"all-gather|all-to-all|all-reduce|collective-permute", hlo)
         seen = []                     # the layout the engine asks for

@@ -21,7 +21,7 @@ from repro.launch.mesh import host_device_flag
 
 REPO = Path(__file__).resolve().parents[1]
 
-# the balance grid: 3 T x 2 V x 20,000 samples, 384 tiles over 4 devices
+# the balance grid: 3 T x 2 V x 20,000 samples, 192 tiles over 4 devices
 BALANCE_SAMPLES = 20_000
 
 _CHILD = textwrap.dedent("""
@@ -38,7 +38,7 @@ _CHILD = textwrap.dedent("""
 
     def grid(**kw):
         base = dict(voltages=(0.6, 1.2), pulse_widths=(2e-12,),
-                    temperatures=(300.0, 350.0, 400.0), n_samples=600,
+                    temperatures=(300.0, 350.0, 400.0), n_samples=1200,
                     dt=0.1e-12, seed=3100000171)
         base.update(kw)
         return CampaignGrid(**base)
@@ -54,13 +54,13 @@ _CHILD = textwrap.dedent("""
     report["variation"] = dealt(
         grid(variation=VariationSpec(corners=(CORNER_TT, CORNER_SS))),
         devices=4)
-    report["split"] = dealt(grid(), devices=4, max_cells_per_launch=2048)
+    report["split"] = dealt(grid(), devices=4, max_cells_per_launch=4096)
     for n_dev in (1, 4):
         text = _pack_program.lower(
             np.uint32(0), np.arange(3, dtype=np.int32),
             np.zeros(3, np.uint32), np.ones(3, np.float32),
             np.ones(3, np.float32), np.ones(2, np.float32), np.float32(9),
-            p=AFMTJ_PARAMS, n_s=600, n_dev=n_dev).as_text()
+            p=AFMTJ_PARAMS, n_s=1200, n_dev=n_dev).as_text()
         report[f"pack_transposes_{n_dev}"] = text.count("stablehlo.transpose")
 
     # real lanes of every (T, V) block on each device, dealt and not

@@ -13,6 +13,13 @@ every test file.
 """
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -22,10 +29,10 @@ from repro.core.params import AFMTJ_PARAMS
 from repro.kernels.bitline_mac import bitline_mac_pallas
 from repro.kernels.fake_analog import (AUX_ROWS, fake_analog_grouped_pallas,
                                        fake_analog_mac_pallas)
-from repro.kernels.llg_rk4 import VAR_ROWS, llg_rk4_pallas
+from repro.kernels.llg_rk4 import CELL_TILE, VAR_ROWS, llg_rk4_pallas
 from repro.kernels.xnor_gemm import xnor_gemm_pallas
 
-CELLS = 32768                     # 64 cell tiles
+CELLS = 32768                     # 32 cell tiles
 M, K, N = 128, 896, 4864          # qwen2-0.5b d_model x d_ff projection
 
 
@@ -61,6 +68,83 @@ def no_persistent_cache():
 def _compiled_text(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# Compiles the thermal kernel (chunk 64) once per lane tile given on the
+# command line and prints, per tile, how many f32 adds, multiplies and
+# subtracts on whole (8, 128) vregs Mosaic's post-apply-vector-layout
+# pass holds.  The dump directory is read when the TPU library loads, so
+# this runs in a process of its own; it prints {"skip": ...} where the
+# library cannot describe the chip.
+_VREG_OPS_CHILD = textwrap.dedent("""
+    import json, os, re, sys
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from repro.core.params import AFMTJ_PARAMS
+    from repro.kernels import llg_rk4
+
+    dump, tiles = sys.argv[1], [int(t) for t in sys.argv[2:]]
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        print(json.dumps({"skip": f"no v5e:2x2 topology: {e}"}))
+        sys.exit(0)
+    sharding = SingleDeviceSharding(topo.devices[0])
+    cells = 2 * max(tiles)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in [((8, cells), jnp.float32), ((cells,), jnp.uint32),
+                         ((cells,), jnp.float32), ((cells,), jnp.float32)]]
+    op = re.compile(r"arith\\.(?:addf|mulf|subf) .*: vector<8x128xf32>$",
+                    re.M)
+    counts = []
+    for tile in tiles:
+        llg_rk4.CELL_TILE = tile
+
+        def fn(st, sd, sg, bd):
+            return llg_rk4.llg_rk4_pallas(
+                st, AFMTJ_PARAMS, 0.1e-12, 4096, thermal_sigma=sg, seeds=sd,
+                step_budget=bd, chunk=64)
+
+        jax.jit(fn).lower(*args).compile()
+        names = os.listdir(dump)
+        [layout] = [n for n in names
+                    if n.endswith("-post-apply-vector-layout.txt")]
+        with open(os.path.join(dump, layout)) as f:
+            counts.append(len(op.findall(f.read())))
+        for n in names:
+            os.remove(os.path.join(dump, n))
+    print(json.dumps({"counts": counts}))
+""")
+
+
+def test_thermal_tile_fills_one_vreg_per_lane_row(tmp_path):
+    """Every per-lane quantity of the thermal step is a ``(1, CELL_TILE)``
+    row that Mosaic tiles ``(1, 128)``, so one vreg holds 1,024 of its
+    lanes.  ``CELL_TILE`` fills that vreg and does not overflow it: the
+    step's f32 vector add/mul/sub count is the same at ``CELL_TILE`` as
+    at 512 lanes (half a vreg) and doubles at ``2 * CELL_TILE``.  First
+    in this file, so its child loads the TPU library before this
+    process's fixtures do."""
+    init = os.environ.get("LIBTPU_INIT_ARGS", "")
+    env = {**os.environ,
+           "PYTHONPATH": str(Path(__file__).resolve().parents[1] / "src"),
+           "TPU_LOG_DIR": "disabled",
+           "LIBTPU_INIT_ARGS": f"{init} --xla_mosaic_dump_to={tmp_path}"}
+    tiles = [512, CELL_TILE, 2 * CELL_TILE]
+    r = subprocess.run(
+        [sys.executable, "-c", _VREG_OPS_CHILD, str(tmp_path),
+         *map(str, tiles)],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-4000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(out["skip"])
+    half, full, double = out["counts"]
+    assert full > 0 and half == full, (half, full)
+    assert double == 2 * full, (full, double)
 
 
 @pytest.mark.parametrize("variant", ["deterministic", "thermal",

@@ -32,6 +32,7 @@ from repro.core import llg
 from repro.core.params import (AFMTJ_PARAMS, CORNER_FF, CORNER_SS, CORNER_TT,
                                MTJ_PARAMS, PROCESS_CORNERS, VariationSpec)
 from repro.kernels import noise, ops, ref
+from repro.kernels.llg_rk4 import CELL_TILE
 
 SPEC3 = VariationSpec(corners=(
     CORNER_FF, CORNER_TT,
@@ -99,7 +100,8 @@ def _packed(cells, seed=0):
     return ops.pack_states(m0, jnp.linspace(0.8, 1.3, cells))
 
 
-@pytest.mark.parametrize("cells,chunk", [(512, 0), (512, 32), (1024, 64)])
+@pytest.mark.parametrize("cells,chunk", [(CELL_TILE, 0), (CELL_TILE, 32),
+                                         (2 * CELL_TILE, 64)])
 def test_variation_kernel_matches_ref(cells, chunk):
     """Per-lane parameter rows: the Pallas kernel and the jnp oracle consume
     identical (alpha, B_k, g_scale) rows and identical thermal streams —
@@ -231,8 +233,8 @@ def test_corner_count_and_values_do_not_enter_compile_key(var_grid):
                  dataclasses.replace(var_grid, variation=spec_b, seed=3),
                  use_cache=False)
     assert _integrate_sharded._cache_size() == 1
-    # 4 corners x 2 T x 512-lane slices = 4096 lanes — same pow2 total
-    # bucket as 3 x 2 x 512 = 3072 -> 4096: corner count is data too
+    # 4 corners x 2 T one-tile slices = 8 tiles — same pow2 total bucket
+    # as 3 x 2 = 6 tiles -> 8: corner count is data too
     spec_c = VariationSpec(corners=(CORNER_TT, CORNER_SS, CORNER_FF,
                                     dataclasses.replace(CORNER_SS, name="sf",
                                                         r_factor=1.3)))
